@@ -201,6 +201,8 @@ def main() -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tasks", type=int, default=96)
     ap.add_argument("--batch-size", type=int, default=8)

@@ -176,6 +176,8 @@ def _maybe_reexec() -> None:
 
 if __name__ == "__main__":
     _maybe_reexec()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="smaller stream for CI")

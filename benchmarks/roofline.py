@@ -1,9 +1,12 @@
 """Roofline analysis (deliverable g): three terms per (arch x shape)
 from the dry-run's compiled artifacts.
 
-    compute    = HLO_FLOPs / (chips x 197 TFLOP/s bf16)
-    memory     = HLO_bytes / (chips x 819 GB/s HBM)
-    collective = collective_bytes / (chips x 50 GB/s ICI)
+    compute    = HLO_FLOPs / (chips x peak bf16 FLOP/s)
+    memory     = HLO_bytes / (chips x peak HBM bytes/s)
+    collective = collective_bytes / (chips x peak ICI bytes/s)
+
+with the peaks of the device kind the dry-run's meshes model, from the
+``PEAKS`` table below.
 
 ``compiled.cost_analysis()`` (and the HLO text the collective bytes are
 parsed from) is the per-partition program, so per-device quantities are
@@ -23,9 +26,25 @@ from typing import Dict, List, Optional
 
 from benchmarks.common import csv_line, write_json
 
-PEAK_FLOPS = 197e12          # bf16 per chip (TPU v5e-like)
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_s": 819e9,
+                    "ici_bytes_s": 1600e9 / 8},
+}
+# the chip launch/dryrun.py's production meshes are sized for
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``device_kind``; an unknown kind raises
+    rather than borrowing another chip's numbers."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
+
 
 DRYRUN_DIR = Path("experiments/dryrun")
 OUT = Path("experiments/bench/roofline.json")
@@ -57,6 +76,7 @@ def _advice(dom: str, rec: dict) -> str:
 def analyse_record(rec: dict) -> Optional[dict]:
     if rec.get("status") != "ok":
         return None
+    pk = peaks(DRYRUN_DEVICE_KIND)
     costs = rec.get("corrected") or rec["raw"]
     chips = rec["chips"]
     # per-partition numbers x chips = global
@@ -64,9 +84,9 @@ def analyse_record(rec: dict) -> Optional[dict]:
     bytes_g = costs["hlo_bytes"] * chips
     coll_g = costs["collective"]["total"] * chips
     terms = {
-        "compute_s": flops_g / (chips * PEAK_FLOPS),
-        "memory_s": bytes_g / (chips * HBM_BW),
-        "collective_s": coll_g / (chips * ICI_BW),
+        "compute_s": flops_g / (chips * pk["flops_bf16"]),
+        "memory_s": bytes_g / (chips * pk["hbm_bytes_s"]),
+        "collective_s": coll_g / (chips * pk["ici_bytes_s"]),
     }
     dom = max(terms, key=terms.get).replace("_s", "")
     model_flops = rec["model_flops"]
@@ -98,8 +118,8 @@ def run(dryrun_dir: Path = DRYRUN_DIR, mesh: str = "single",
         if row:
             rows.append(row)
     out = {"rows": rows, "skipped": skips, "mesh": mesh,
-           "constants": {"peak_flops": PEAK_FLOPS, "hbm_bw": HBM_BW,
-                         "ici_bw": ICI_BW}}
+           "device_kind": DRYRUN_DEVICE_KIND,
+           "constants": peaks(DRYRUN_DEVICE_KIND)}
     write_json(OUT, out)
     if verbose:
         hdr = (f"{'arch':24s} {'shape':12s} {'compute':>10s} "

@@ -15,11 +15,13 @@ the chunk query at absolute position ``qp`` iff ``kp <= qp`` (causal)
 — which also masks every slot past the chunk's own writes, so stale
 bytes in recycled pages never reach the softmax.
 
-Layout notes: as in the decode kernel, a page is a ``(page_size,
-head_dim)`` VMEM tile per kv-head; the chunk adds a ``(C, G, Dk)`` q
-tile. ``C * G`` should be a multiple of 8 sublanes for f32 — the
-serving default (chunk 8, G >= 1) satisfies this; smaller chunks still
-compile, just with padded tiles.
+Layout notes: pages are head-major, ``(P, KV, page_size, head_dim)``
+(see kernels/paged_decode_attention.py), so a page is one
+``(page_size, head_dim)`` block. The chunk's queries for one kv-head
+are flattened to a ``(C * G, head_dim)`` tile — row ``c * G + g`` is
+group head ``g`` at chunk position ``c`` — so every contraction is a
+plain 2-D matmul, and each row's absolute query position rides in a
+``(C * G, 1)`` int32 column beside it.
 """
 from __future__ import annotations
 
@@ -34,7 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 def _chunk_prefill_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref,
                           o_ref, m_ref, l_ref, acc_ref, *,
                           page_size: int, scale: float):
-    bi = pl.program_id(0)
     ni = pl.program_id(2)
     n_b = pl.num_programs(2)
 
@@ -44,38 +45,32 @@ def _chunk_prefill_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0].astype(jnp.float32) * scale     # (C, G, Dk)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (page, Dk)
-    v = v_ref[0, :, 0].astype(jnp.float32)             # (page, Dv)
-    c, g = q.shape[0], q.shape[1]
+    q = q_ref[0, 0].astype(jnp.float32) * scale        # (C*G, Dk)
+    k = k_ref[0, 0].astype(jnp.float32)                # (page, Dk)
+    v = v_ref[0, 0].astype(jnp.float32)                # (page, Dv)
 
-    s = jnp.einsum("cgd,pd->cgp", q, k,
-                   preferred_element_type=jnp.float32)  # (C, G, page)
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
     key_pos = ni * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, page_size), 2)
-    q_pos = qpos_ref[bi].reshape(c, 1, 1)
-    s = jnp.where(key_pos <= q_pos, s, -jnp.inf)
+        jnp.int32, (1, page_size), 1)
+    s = jnp.where(key_pos <= qpos_ref[0], s, -jnp.inf)
 
-    m_prev = m_ref[...].reshape(c, g, 1)
+    m_prev = m_ref[...]                                # (C*G, 1)
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
     p = jnp.where(jnp.isfinite(m_new), p, 0.0)
     alpha = jnp.where(jnp.isfinite(m_prev), alpha, 0.0)
 
-    l_ref[...] = (l_ref[...].reshape(c, g, 1) * alpha
-                  + p.sum(axis=-1, keepdims=True)).reshape(c, g)
-    acc_ref[...] = (acc_ref[...].reshape(c, g, -1) * alpha
-                    + jnp.einsum("cgp,pd->cgd", p, v,
-                                 preferred_element_type=jnp.float32)
-                    ).reshape(c, g, -1)
-    m_ref[...] = m_new.reshape(c, g)
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
 
     @pl.when(ni == n_b - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...].reshape(c, g, 1), 1e-30)
-        o_ref[0, :, 0] = (acc_ref[...].reshape(c, g, -1)
-                          / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...]
+                       / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -86,50 +81,54 @@ def chunked_prefill_attention(q: jax.Array, k_pages: jax.Array,
                               q_positions: jax.Array, *,
                               prompt_len: int,
                               interpret: bool = False) -> jax.Array:
-    """q: (B, C, H, Dk) chunk queries; k_pages/v_pages: (P, page_size,
-    KV, Dk/Dv); block_table: (B, NB) int32 page ids; q_positions:
-    (B, C) int32 absolute positions of each row's chunk (rows may sit
-    at different prefill depths); prompt_len: static total prompt
-    length (pages past it are never touched). The chunk's own K/V
-    must already be written into the pages. Returns (B, C, H, Dv)."""
+    """q: (B, C, H, Dk) chunk queries; k_pages/v_pages: (P, KV,
+    page_size, Dk/Dv); block_table: (B, NB) int32 page ids;
+    q_positions: (B, C) int32 absolute positions of each row's chunk
+    (rows may sit at different prefill depths); prompt_len: static
+    total prompt length (pages past it are never touched). The chunk's
+    own K/V must already be written into the pages. Returns
+    (B, C, H, Dv)."""
     b, c, h, dk = q.shape
-    page_size, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, page_size = k_pages.shape[1], k_pages.shape[2]
     dv = v_pages.shape[-1]
     g = h // kv
+    cg = c * g
     nb_used = -(-prompt_len // page_size)
     scale = 1.0 / (dk ** 0.5)
 
-    qk = q.reshape(b, c, kv, g, dk)                    # (B, C, KV, G, Dk)
+    qk = q.reshape(b, c, kv, g, dk).transpose(0, 2, 1, 3, 4).reshape(
+        b, kv, cg, dk)                                 # (B, KV, C*G, Dk)
+    qpos = jnp.repeat(q_positions.astype(jnp.int32), g,
+                      axis=1)[..., None]               # (B, C*G, 1)
     block_table = block_table.astype(jnp.int32)
-    q_positions = q_positions.astype(jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # block_table, q_positions
+        num_scalar_prefetch=1,           # block_table
         grid=(b, kv, nb_used),
         in_specs=[
-            pl.BlockSpec((1, c, 1, g, dk),
-                         lambda bi, ki, ni, bt, qp: (bi, 0, ki, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dk),
-                         lambda bi, ki, ni, bt, qp:
-                         (bt[bi, ni], 0, ki, 0)),
-            pl.BlockSpec((1, page_size, 1, dv),
-                         lambda bi, ki, ni, bt, qp:
-                         (bt[bi, ni], 0, ki, 0)),
+            pl.BlockSpec((1, cg, 1),
+                         lambda bi, ki, ni, bt: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, cg, dk),
+                         lambda bi, ki, ni, bt: (bi, ki, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, dk),
+                         lambda bi, ki, ni, bt: (bt[bi, ni], ki, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, dv),
+                         lambda bi, ki, ni, bt: (bt[bi, ni], ki, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, c, 1, g, dv),
-                               lambda bi, ki, ni, bt, qp:
-                               (bi, 0, ki, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, cg, dv),
+                               lambda bi, ki, ni, bt: (bi, ki, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((c, g), jnp.float32),       # running max m
-            pltpu.VMEM((c, g), jnp.float32),       # running sum l
-            pltpu.VMEM((c, g, dv), jnp.float32),   # output accumulator
+            pltpu.VMEM((cg, 1), jnp.float32),      # running max m
+            pltpu.VMEM((cg, 1), jnp.float32),      # running sum l
+            pltpu.VMEM((cg, dv), jnp.float32),     # output accumulator
         ],
     )
     out = pl.pallas_call(
         functools.partial(_chunk_prefill_kernel, page_size=page_size,
                           scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, kv, g, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, cg, dv), q.dtype),
         interpret=interpret,
-    )(block_table, q_positions, qk, k_pages, v_pages)
-    return out.reshape(b, c, h, dv)
+    )(block_table, qpos, qk, k_pages, v_pages)
+    return out.reshape(b, kv, c, g, dv).transpose(0, 2, 1, 3, 4).reshape(
+        b, c, h, dv)

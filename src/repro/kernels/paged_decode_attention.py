@@ -9,10 +9,12 @@ from the scalar-prefetched block table (so the DMA for page ``n+1``
 can be issued while page ``n`` computes), and the online-softmax state
 (m, l, acc) rides in VMEM scratch exactly as in the dense kernel.
 
-Layout notes: a page is a ``(page_size, head_dim)`` VMEM tile per
-kv-head. With head_dim 128 on the lane axis, ``page_size`` >= 8 keeps
-f32 pages at the minimum (8, 128) tile — the serving default of 8
-tokens/page is exactly one tile, so a page DMA is never partial.
+Layout notes: pages are head-major, ``(P, KV, page_size, head_dim)``,
+so one grid step reads a ``(page_size, head_dim)`` block whose two
+minor dimensions are the whole of the array's — the shape Mosaic
+accepts for any page size and head dim. (A token-major
+``(P, page_size, KV, head_dim)`` pool would need a block of 1 on the
+KV axis, which the TPU's (8, 128) tiling rule refuses.)
 
 Grid: (B, KV, NB) — the page axis iterates innermost so the scratch
 carries across one row's pages. Rows shorter than NB pages mask by
@@ -43,8 +45,8 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, Dk)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (page, Dk)
-    v = v_ref[0, :, 0].astype(jnp.float32)             # (page, Dv)
+    k = k_ref[0, 0].astype(jnp.float32)                # (page, Dk)
+    v = v_ref[0, 0].astype(jnp.float32)                # (page, Dv)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
     positions = ni * page_size + jax.lax.broadcasted_iota(
@@ -76,11 +78,11 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_table: jax.Array,
                            lengths: jax.Array, *,
                            interpret: bool = False) -> jax.Array:
-    """q: (B, H, Dk); k_pages/v_pages: (P, page_size, KV, Dk/Dv);
+    """q: (B, H, Dk); k_pages/v_pages: (P, KV, page_size, Dk/Dv);
     block_table: (B, NB) int32 page ids; lengths: (B,) int32 valid
     positions per row. Returns (B, H, Dv)."""
     b, h, dk = q.shape
-    page_size, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, page_size = k_pages.shape[1], k_pages.shape[2]
     dv = v_pages.shape[-1]
     nb = block_table.shape[1]
     g = h // kv
@@ -96,12 +98,12 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, g, dk),
                          lambda bi, ki, ni, bt, ln: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dk),
+            pl.BlockSpec((1, 1, page_size, dk),
                          lambda bi, ki, ni, bt, ln:
-                         (bt[bi, ni], 0, ki, 0)),
-            pl.BlockSpec((1, page_size, 1, dv),
+                         (bt[bi, ni], ki, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, dv),
                          lambda bi, ki, ni, bt, ln:
-                         (bt[bi, ni], 0, ki, 0)),
+                         (bt[bi, ni], ki, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
                                lambda bi, ki, ni, bt, ln:

@@ -19,6 +19,13 @@ The int8->f32 widen happens on the VPU after the VMEM load, so the
 MXU contraction runs on the widened page while HBM only ever sees
 int8 codes + one f32 scale per vector.
 
+Layout: head-major like the dense pool — codes ``(P, KV, page_size,
+Dh)``, scale planes ``(P, KV, page_size)``. A code block is one
+``(page_size, Dh)`` tile; a scale block is the page's whole
+``(KV, page_size)`` plane, from which the kernel takes its kv-head's
+row (a one-row block on the KV axis would break the (8, 128) tiling
+rule).
+
 Grid: (B, KV, NB) — page axis innermost so the scratch carries across
 one row's pages. Rows shorter than NB pages mask by ``lengths[b]``;
 spare block-table slots must hold *valid* page ids (the pool
@@ -39,6 +46,7 @@ def _paged_decode_quant_kernel(bt_ref, len_ref, q_ref, k_ref, ks_ref,
                                acc_ref, *, page_size: int,
                                scale: float):
     bi = pl.program_id(0)
+    ki = pl.program_id(1)
     ni = pl.program_id(2)
     n_b = pl.num_programs(2)
 
@@ -49,13 +57,13 @@ def _paged_decode_quant_kernel(bt_ref, len_ref, q_ref, k_ref, ks_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, Dk)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (page, Dk) int8
-    kscale = ks_ref[0, :, 0].astype(jnp.float32)       # (page,)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    vscale = vs_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                # (page, Dk) int8
+    kscale = ks_ref[0, pl.ds(ki, 1), :]                # (1, page) f32
+    v = v_ref[0, 0].astype(jnp.float32)
+    vscale = vs_ref[0, pl.ds(ki, 1), :]
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-    s = s * kscale[None, :]                            # fold k scales
+    s = s * kscale                                     # fold k scales
     positions = ni * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)
     valid = positions < len_ref[bi]
@@ -69,7 +77,7 @@ def _paged_decode_quant_kernel(bt_ref, len_ref, q_ref, k_ref, ks_ref,
     alpha = jnp.where(jnp.isfinite(m_prev), alpha, 0.0)
 
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    pv = p * vscale[None, :]                           # fold v scales
+    pv = p * vscale                                    # fold v scales
     acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
         pv, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -89,12 +97,12 @@ def paged_decode_attention_quant(q: jax.Array, k_pages: jax.Array,
                                  block_table: jax.Array,
                                  lengths: jax.Array, *,
                                  interpret: bool = False) -> jax.Array:
-    """q: (B, H, Dk); k_pages/v_pages: (P, page_size, KV, Dk/Dv) int8;
-    k_scale_pages/v_scale_pages: (P, page_size, KV) f32;
+    """q: (B, H, Dk); k_pages/v_pages: (P, KV, page_size, Dk/Dv) int8;
+    k_scale_pages/v_scale_pages: (P, KV, page_size) f32;
     block_table: (B, NB) int32 page ids; lengths: (B,) int32 valid
     positions per row. Returns (B, H, Dv)."""
     b, h, dk = q.shape
-    page_size, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, page_size = k_pages.shape[1], k_pages.shape[2]
     dv = v_pages.shape[-1]
     nb = block_table.shape[1]
     g = h // kv
@@ -110,18 +118,18 @@ def paged_decode_attention_quant(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, g, dk),
                          lambda bi, ki, ni, bt, ln: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dk),
+            pl.BlockSpec((1, 1, page_size, dk),
                          lambda bi, ki, ni, bt, ln:
-                         (bt[bi, ni], 0, ki, 0)),
-            pl.BlockSpec((1, page_size, 1),
+                         (bt[bi, ni], ki, 0, 0)),
+            pl.BlockSpec((1, kv, page_size),
                          lambda bi, ki, ni, bt, ln:
-                         (bt[bi, ni], 0, ki)),
-            pl.BlockSpec((1, page_size, 1, dv),
+                         (bt[bi, ni], 0, 0)),
+            pl.BlockSpec((1, 1, page_size, dv),
                          lambda bi, ki, ni, bt, ln:
-                         (bt[bi, ni], 0, ki, 0)),
-            pl.BlockSpec((1, page_size, 1),
+                         (bt[bi, ni], ki, 0, 0)),
+            pl.BlockSpec((1, kv, page_size),
                          lambda bi, ki, ni, bt, ln:
-                         (bt[bi, ni], 0, ki)),
+                         (bt[bi, ni], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
                                lambda bi, ki, ni, bt, ln:

@@ -36,25 +36,42 @@ def decode_attention_ref(q: jax.Array, k_cache: jax.Array,
     return out.reshape(b, h, v_cache.shape[-1]).astype(q.dtype)
 
 
+def gather_pages(pages: jax.Array, block_table: jax.Array,
+                 length: Optional[int] = None) -> jax.Array:
+    """Head-major pages ``(P, KV, page_size, ...)`` gathered through a
+    ``(B, NB)`` block table into each row's token-major contiguous view
+    ``(B, length, KV, ...)`` (``length`` defaults to ``NB *
+    page_size``) — the dense cache layout the attention math reads. A
+    pure copy, and one gather: the token-major result comes straight
+    out of it, so the contractions downstream see the same operand
+    layout as a dense cache's."""
+    ps = pages.shape[2]
+    t = jnp.arange(block_table.shape[1] * ps if length is None
+                   else length)
+    page_ids = block_table[:, t // ps]                  # (B, length)
+    heads = jnp.arange(pages.shape[1])
+    return pages[page_ids[:, :, None], heads[None, None, :],
+                 (t % ps)[None, :, None]]
+
+
 def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array,
                                block_table: jax.Array,
                                lengths: jax.Array) -> jax.Array:
     """Single-token GQA decode attention over a paged KV cache.
 
-    q: (B, H, Dk); k_pages/v_pages: (P, page_size, KV, Dk/Dv);
+    q: (B, H, Dk); k_pages/v_pages: (P, KV, page_size, Dk/Dv);
     block_table: (B, NB) int32 page ids per row; lengths: (B,) int32
     valid positions per row. Gathers each row's pages into a
     contiguous view and attends over the valid prefix; math in f32.
     Returns (B, H, Dv).
     """
     b, h, dk = q.shape
-    page_size, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, page_size = k_pages.shape[1], k_pages.shape[2]
     nb = block_table.shape[1]
     g = h // kv
-    k_cache = k_pages[block_table].reshape(b, nb * page_size, kv, dk)
-    v_cache = v_pages[block_table].reshape(b, nb * page_size, kv,
-                                           v_pages.shape[-1])
+    k_cache = gather_pages(k_pages, block_table)
+    v_cache = gather_pages(v_pages, block_table)
     scale = 1.0 / jnp.sqrt(jnp.float32(dk))
     qr = q.reshape(b, kv, g, dk).astype(jnp.float32) * scale
     scores = jnp.einsum("bkgd,bskd->bkgs", qr,
@@ -75,8 +92,8 @@ def paged_decode_attention_quant_ref(q: jax.Array, k_pages: jax.Array,
                                      lengths: jax.Array) -> jax.Array:
     """Single-token GQA decode over int8-quantised paged KV.
 
-    q: (B, H, Dk); k_pages/v_pages: (P, page_size, KV, Dk/Dv) int8
-    codes; k_scale_pages/v_scale_pages: (P, page_size, KV) f32
+    q: (B, H, Dk); k_pages/v_pages: (P, KV, page_size, Dk/Dv) int8
+    codes; k_scale_pages/v_scale_pages: (P, KV, page_size) f32
     per-vector scales; block_table: (B, NB) int32 page ids; lengths:
     (B,) int32 valid positions per row. Scales fold into the
     attention math exactly as in
@@ -86,14 +103,13 @@ def paged_decode_attention_quant_ref(q: jax.Array, k_pages: jax.Array,
     Math in f32; returns (B, H, Dv).
     """
     b, h, dk = q.shape
-    page_size, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, page_size = k_pages.shape[1], k_pages.shape[2]
     nb = block_table.shape[1]
     g = h // kv
-    k_cache = k_pages[block_table].reshape(b, nb * page_size, kv, dk)
-    v_cache = v_pages[block_table].reshape(b, nb * page_size, kv,
-                                           v_pages.shape[-1])
-    k_scale = k_scale_pages[block_table].reshape(b, nb * page_size, kv)
-    v_scale = v_scale_pages[block_table].reshape(b, nb * page_size, kv)
+    k_cache = gather_pages(k_pages, block_table)
+    v_cache = gather_pages(v_pages, block_table)
+    k_scale = gather_pages(k_scale_pages, block_table)
+    v_scale = gather_pages(v_scale_pages, block_table)
     scale = 1.0 / jnp.sqrt(jnp.float32(dk))
     qr = q.reshape(b, kv, g, dk).astype(jnp.float32) * scale
     scores = jnp.einsum("bkgd,bskd->bkgs", qr,
@@ -117,20 +133,17 @@ def chunked_prefill_attention_ref(q: jax.Array, k_pages: jax.Array,
 
     q: (B, C, H, Dk) chunk queries at absolute positions
     ``q_positions`` (B, C) — rows may sit at different prefill
-    depths; k_pages/v_pages: (P, page_size, KV, Dk/Dv); block_table:
+    depths; k_pages/v_pages: (P, KV, page_size, Dk/Dv); block_table:
     (B, NB) int32 page ids. The chunk's own K/V must already be
     written into the pages. Gathers each row's pages to the static
     ``prompt_len`` and attends causally (key position <= query
     position); math in f32. Returns (B, C, H, Dv).
     """
     b, c, h, dk = q.shape
-    page_size, kv = k_pages.shape[1], k_pages.shape[2]
-    nb = block_table.shape[1]
+    kv = k_pages.shape[1]
     g = h // kv
-    k_cache = k_pages[block_table].reshape(
-        b, nb * page_size, kv, dk)[:, :prompt_len]
-    v_cache = v_pages[block_table].reshape(
-        b, nb * page_size, kv, v_pages.shape[-1])[:, :prompt_len]
+    k_cache = gather_pages(k_pages, block_table, prompt_len)
+    v_cache = gather_pages(v_pages, block_table, prompt_len)
     scale = 1.0 / jnp.sqrt(jnp.float32(dk))
     qr = q.reshape(b, c, kv, g, dk).astype(jnp.float32) * scale
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qr,

@@ -1,13 +1,9 @@
-from repro.xla_flags import force_host_device_count
-force_host_device_count(512)
-# The two lines above MUST run before any jax-touching import: jax
-# locks the device count on first initialisation, and the production
-# meshes below need 512 placeholder host devices. The helper *merges*
-# into any user-exported XLA_FLAGS (preserving their other flags and
-# their own device-count override) instead of clobbering the variable.
-# Smoke tests and benches see 1 CPU — nothing else sets this flag.
 """Multi-pod dry-run: lower + compile every (architecture x input
 shape) on the production meshes and extract roofline inputs.
+
+A CPU-only compile tool: importing this module forces 512 placeholder
+*host* devices, so it must never share a process with code that needs
+the chip (``chip_smoke.py`` does not import it).
 
 Per combo this emits ``experiments/dryrun/<arch>__<shape>__<mesh>.json``
 with: HLO FLOPs + bytes (``compiled.cost_analysis()``), per-device
@@ -19,6 +15,14 @@ all-reduce / reduce-scatter / all-to-all / collective-permute).
     PYTHONPATH=src python -m repro.launch.dryrun \
         --arch llama3-8b --shape train_4k --mesh single
 """
+from repro.xla_flags import force_host_device_count
+force_host_device_count(512)
+# The two lines above MUST run before any jax-touching import: jax
+# locks the device count on first initialisation, and the production
+# meshes below need 512 placeholder host devices. The helper *merges*
+# into any user-exported XLA_FLAGS (preserving their other flags and
+# their own device-count override) instead of clobbering the variable.
+# Smoke tests and benches see 1 CPU — nothing else sets this flag.
 import argparse
 import json
 import re

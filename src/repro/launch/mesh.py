@@ -5,11 +5,12 @@ module never touches jax device state — the dry-run sets
 ``--xla_force_host_platform_device_count`` before first jax init, and
 smoke tests must keep seeing one device.
 
-Target hardware: TPU v5e-like pods — 256 chips/pod, 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI. Single pod is a (16, 16) ("data",
-"model") mesh; multi-pod prepends a "pod" axis that extends data
-parallelism (gradient all-reduce crosses pods in training; pure request
-parallelism in serving).
+Target hardware: TPU v5e pods — 256 chips/pod; per chip 197 TFLOP/s
+bf16, 819 GB/s HBM and 1,600 Gbit/s of chip-to-chip interconnect
+(Google Cloud, "TPU v5e"; the table is ``benchmarks/roofline.PEAKS``).
+Single pod is a (16, 16) ("data", "model") mesh; multi-pod prepends a
+"pod" axis that extends data parallelism (gradient all-reduce crosses
+pods in training; pure request parallelism in serving).
 """
 from __future__ import annotations
 

@@ -1,15 +1,19 @@
-from repro.xla_flags import force_host_device_count
-force_host_device_count(512)
-# Must run before any jax-touching import — see launch/dryrun.py
-# (merges into user-set XLA_FLAGS instead of clobbering them).
 """§Perf hillclimb runner: named (arch, shape, rules, config-transform)
 variants, lowered on the single-pod production mesh, recorded to
 experiments/perf/<variant>.json with the same cost extraction as the
 dry-run. EXPERIMENTS.md §Perf documents each hypothesis -> change ->
 before -> after cycle.
 
+A CPU-only compile tool, like ``launch/dryrun.py``: importing it
+forces 512 placeholder host devices, so it never shares a process
+with code that needs the chip.
+
     PYTHONPATH=src python -m repro.launch.perf --variant A2 [--all]
 """
+from repro.xla_flags import force_host_device_count
+force_host_device_count(512)
+# Must run before any jax-touching import — see launch/dryrun.py
+# (merges into user-set XLA_FLAGS instead of clobbering them).
 import argparse
 import dataclasses
 import json

@@ -219,6 +219,8 @@ def serve(tasks: Sequence[Task], probe: ZooModel,
 
 
 def main(argv=None):
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tasks", type=int, default=32)
     ap.add_argument("--train-steps", type=int, default=300)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.ref import gather_pages
 from repro.models.layers import apply_rope
 from repro.sharding import shard, tp_all_gather
 
@@ -343,7 +344,7 @@ def gqa_decode_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Single-token GQA decode against a paged KV cache.
 
-    x_t: (B, d); k_pages/v_pages: (P, page_size, KV, Dh) — one layer's
+    x_t: (B, d); k_pages/v_pages: (P, KV, page_size, Dh) — one layer's
     slice of the page pool; block_table: (B, NB) int32 page ids;
     pos: scalar int32, or (B,) int32 per-row positions (step-level
     serving mixes rows at different depths in one decode batch);
@@ -370,7 +371,7 @@ def gqa_decode_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
         q = apply_rope(q[:, None], pos_b, cfg.rope_theta)[:, 0]
         k = apply_rope(k[:, None], pos_b, cfg.rope_theta)[:, 0]
 
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     if per_row:
         page_ids = jnp.take_along_axis(
             block_table, (pos // ps)[:, None], axis=1)[:, 0]  # (B,)
@@ -378,9 +379,9 @@ def gqa_decode_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
     else:
         page_ids = jnp.take(block_table, pos // ps, axis=1)   # (B,)
         slot = pos % ps
-    k_pages = k_pages.at[page_ids, slot].set(
+    k_pages = k_pages.at[page_ids, :, slot].set(
         k.astype(k_pages.dtype))
-    v_pages = v_pages.at[page_ids, slot].set(
+    v_pages = v_pages.at[page_ids, :, slot].set(
         v.astype(v_pages.dtype))
 
     if cfg.use_pallas:
@@ -392,10 +393,8 @@ def gqa_decode_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
         out = ops.paged_decode_attention(q, k_pages, v_pages,
                                          block_table, lengths)
     else:
-        k_cache = k_pages[block_table].reshape(
-            b, -1, kv, hd)[:, :cache_len]
-        v_cache = v_pages[block_table].reshape(
-            b, -1, kv, hd)[:, :cache_len]
+        k_cache = gather_pages(k_pages, block_table, cache_len)
+        v_cache = gather_pages(v_pages, block_table, cache_len)
         out = decode_attention(q, k_cache, v_cache,
                                jnp.arange(cache_len), pos)
     out = out.reshape(b, cfg.num_heads * hd)
@@ -416,8 +415,8 @@ def gqa_decode_quant_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
     """Single-token GQA decode against int8-quantised KV pages.
 
     x_t: (B, d); pages: one layer's slice of the quant pool —
-    {"k","v"}: (P, page_size, KV, Dh) int8 codes, {"k_scale",
-    "v_scale"}: (P, page_size, KV) f32 per-vector scales;
+    {"k","v"}: (P, KV, page_size, Dh) int8 codes, {"k_scale",
+    "v_scale"}: (P, KV, page_size) f32 per-vector scales;
     block_table: (B, NB) page ids; pos: scalar or (B,) int32;
     cache_len: static dense-equivalent cache length.
 
@@ -446,7 +445,7 @@ def gqa_decode_quant_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
 
-    ps = pages["k"].shape[1]
+    ps = pages["k"].shape[2]
     if per_row:
         page_ids = jnp.take_along_axis(
             block_table, (pos // ps)[:, None], axis=1)[:, 0]  # (B,)
@@ -455,11 +454,11 @@ def gqa_decode_quant_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
         page_ids = jnp.take(block_table, pos // ps, axis=1)
         slot = pos % ps
     pages = {
-        "k": pages["k"].at[page_ids, slot].set(kq),
-        "v": pages["v"].at[page_ids, slot].set(vq),
-        "k_scale": pages["k_scale"].at[page_ids, slot].set(
+        "k": pages["k"].at[page_ids, :, slot].set(kq),
+        "v": pages["v"].at[page_ids, :, slot].set(vq),
+        "k_scale": pages["k_scale"].at[page_ids, :, slot].set(
             ks.astype(pages["k_scale"].dtype)),
-        "v_scale": pages["v_scale"].at[page_ids, slot].set(
+        "v_scale": pages["v_scale"].at[page_ids, :, slot].set(
             vs.astype(pages["v_scale"].dtype)),
     }
 
@@ -473,14 +472,9 @@ def gqa_decode_quant_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
             q, pages["k"], pages["k_scale"], pages["v"],
             pages["v_scale"], block_table, lengths)
     else:
-        k_cache = pages["k"][block_table].reshape(
-            b, -1, kv, hd)[:, :cache_len]
-        v_cache = pages["v"][block_table].reshape(
-            b, -1, kv, hd)[:, :cache_len]
-        k_scale = pages["k_scale"][block_table].reshape(
-            b, -1, kv)[:, :cache_len]
-        v_scale = pages["v_scale"][block_table].reshape(
-            b, -1, kv)[:, :cache_len]
+        k_cache, v_cache, k_scale, v_scale = (
+            gather_pages(pages[name], block_table, cache_len)
+            for name in ("k", "v", "k_scale", "v_scale"))
         out = decode_attention_quant(q, k_cache, k_scale, v_cache,
                                      v_scale, jnp.arange(cache_len),
                                      pos)
@@ -498,7 +492,7 @@ def gqa_decode_ring_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
                           ) -> Tuple[jax.Array, dict]:
     """Single-token sliding-window GQA decode against ring pages.
 
-    x_t: (B, d); pages: one layer's {"k","v"} (P, page_size, KV, Dh);
+    x_t: (B, d); pages: one layer's {"k","v"} (P, KV, page_size, Dh);
     block_table: (B, NB) page ids covering exactly
     ceil(cache_len/page_size) pages (NB never grows past the window);
     pos: scalar or (B,) int32; cache_len: the ring length —
@@ -528,22 +522,20 @@ def gqa_decode_ring_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
         k = apply_rope(k[:, None], pos_b, cfg.rope_theta)[:, 0]
 
     pos_rows = pos if per_row else jnp.broadcast_to(pos, (b,))
-    ps = pages["k"].shape[1]
+    ps = pages["k"].shape[2]
     slot = jnp.mod(pos_rows, cache_len)                   # (B,)
     page_ids = jnp.take_along_axis(
         block_table, (slot // ps)[:, None], axis=1)[:, 0]
     offset = slot % ps
     pages = {
-        "k": pages["k"].at[page_ids, offset].set(
+        "k": pages["k"].at[page_ids, :, offset].set(
             k.astype(pages["k"].dtype)),
-        "v": pages["v"].at[page_ids, offset].set(
+        "v": pages["v"].at[page_ids, :, offset].set(
             v.astype(pages["v"].dtype)),
     }
 
-    k_cache = pages["k"][block_table].reshape(
-        b, -1, kv, hd)[:, :cache_len]
-    v_cache = pages["v"][block_table].reshape(
-        b, -1, kv, hd)[:, :cache_len]
+    k_cache = gather_pages(pages["k"], block_table, cache_len)
+    v_cache = gather_pages(pages["v"], block_table, cache_len)
     # absolute position currently held in each ring slot, per row
     slots = jnp.arange(cache_len)[None]                   # (1, CL)
     k_positions = pos_rows[:, None] - jnp.mod(
@@ -570,7 +562,7 @@ def gqa_prefill_chunk_paged(cfg: ModelConfig, p: dict, x: jax.Array,
     *per-row* chunk offsets — traced, not static, so rows at different
     prefill depths share one compiled program (the step loop batches
     every row needing a chunk this tick into one launch);
-    k_pages/v_pages: (P, page_size, KV, Dh) one layer's page-pool
+    k_pages/v_pages: (P, KV, page_size, Dh) one layer's page-pool
     slice; block_table: (B, NB) page ids covering at least
     ``prompt_len`` positions. Writes the chunk's rope'd K/V into the
     pages, then attends the chunk queries over the gathered page view.
@@ -603,13 +595,13 @@ def gqa_prefill_chunk_paged(cfg: ModelConfig, p: dict, x: jax.Array,
     # scatter the chunk's K/V into the pages first, so attention reads
     # every key (prefix and self) from the same storage the decode
     # steps will — and so the Pallas kernel path needs no concat
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     page_ids = jnp.take_along_axis(block_table, positions // ps,
                                    axis=1)                 # (B, C)
     slots = positions % ps
-    k_pages = k_pages.at[page_ids, slots].set(
+    k_pages = k_pages.at[page_ids, :, slots].set(
         k.astype(k_pages.dtype))
-    v_pages = v_pages.at[page_ids, slots].set(
+    v_pages = v_pages.at[page_ids, :, slots].set(
         v.astype(v_pages.dtype))
 
     if cfg.use_pallas:
@@ -620,11 +612,8 @@ def gqa_prefill_chunk_paged(cfg: ModelConfig, p: dict, x: jax.Array,
             q, k_pages, v_pages, block_table, positions,
             prompt_len=prompt_len)
     else:
-        nb = block_table.shape[1]
-        k_all = k_pages[block_table].reshape(
-            b, nb * ps, kv, hd)[:, :prompt_len]
-        v_all = v_pages[block_table].reshape(
-            b, nb * ps, kv, hd)[:, :prompt_len]
+        k_all = gather_pages(k_pages, block_table, prompt_len)
+        v_all = gather_pages(v_pages, block_table, prompt_len)
         mask = positions[:, :, None] >= \
             jnp.arange(prompt_len)[None, None]             # (B, C, S)
         out = full_attention(q, k_all, v_all, mask)

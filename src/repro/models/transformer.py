@@ -581,8 +581,8 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
 
     tokens: (B, S); pages: the pool's page pytree — every leaf has
     leading axes (L, P, ...): dense holds {k, v} bf16
-    (L, P, page_size, KV, Dh); quant adds int8 codes plus
-    {k_scale, v_scale} f32 (L, P, page_size, KV) planes; ring is the
+    (L, P, KV, page_size, Dh); quant adds int8 codes plus
+    {k_scale, v_scale} f32 (L, P, KV, page_size) planes; ring is the
     dense leaf set over ceil(window/page) pages per row.
     prefill_table: (B, NBp) int32 page ids covering the row's prompt
     pages (rows must not alias writable pages); cache_len: the
@@ -597,7 +597,7 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     layout = resolve_layout(cfg)
     assert layout in ("dense", "quant", "ring"), cfg.name
     b, s = tokens.shape
-    ps = pages["k"].shape[2]
+    ps = pages["k"].shape[3]
     nbp = prefill_table.shape[1]
     positions = jnp.arange(s)
     x = _embed_inputs(cfg, params, tokens, None)
@@ -636,10 +636,10 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
         vq, vsc = attn.quantize_kv(vs)
         entry = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
 
-    # pack (L, B, S', ...) into pages: pad S' to the page boundary and
-    # scatter page-shaped chunks at the block-table ids (pad chunks land
-    # in the partial tail page's dead slots, matching the dense cache's
-    # zero padding)
+    # pack (L, B, S', KV, ...) into head-major pages: pad S' to the
+    # page boundary and scatter (KV, page)-shaped chunks at the
+    # block-table ids (pad chunks land in the partial tail page's dead
+    # slots, matching the dense cache's zero padding)
     s_pad = nbp * ps
 
     def pack(a):
@@ -647,7 +647,8 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
             pad = [(0, 0)] * a.ndim
             pad[2] = (0, s_pad - a.shape[2])
             a = jnp.pad(a, pad)
-        return a.reshape((cfg.num_layers, b, nbp, ps) + a.shape[3:])
+        a = a.reshape((cfg.num_layers, b, nbp, ps) + a.shape[3:])
+        return jnp.swapaxes(a, 3, 4)
 
     pages = {name: pages[name].at[:, prefill_table].set(
                  pack(entry[name]).astype(pages[name].dtype))

@@ -263,7 +263,7 @@ def prefill_paged(cfg: ModelConfig, params: dict,
     """Prompt prefill scattering K/V into pool pages.
 
     prompt_tokens: (B, S); pages: the pool's page pytree (leaves
-    (L, P, page_size, ...) — dense {k, v}, quant adds the f32
+    (L, P, KV, page_size, ...) — dense {k, v}, quant adds the f32
     {k_scale, v_scale} planes); prefill_table: (B, NBp) int32;
     cache_len: dense-equivalent total length, required for ring
     layouts. Returns (logits0 (B, V), pages). Logits are bit-identical
@@ -504,22 +504,15 @@ def _row_spec():
 
 def _page_specs(pages, m: int):
     """Per-leaf specs for a sharded page pytree. Code/value leaves
-    (n_shards, L, P, page, KV, Dh) and scale planes (n_shards, L, P,
-    page, KV) both put rows over "data"; under tensor parallelism each
+    (n_shards, L, P, KV, page, Dh) and scale planes (n_shards, L, P,
+    KV, page) both put rows over "data"; under tensor parallelism each
     model column stores only its kv-head slice, so the KV axis shards
     over "model" (per-shard pool bytes divide by m — capacity at a
     fixed byte budget scales x m). Only the "dense" and "quant"
-    layouts reach the sharded runners, so every leaf has KV at axis 4."""
+    layouts reach the sharded runners, so every leaf has KV at axis 3."""
     from jax.sharding import PartitionSpec as P
-
-    def leaf(a):
-        if m <= 1:
-            return P("data")
-        if a.ndim == 6:
-            return P("data", None, None, None, "model", None)
-        return P("data", None, None, None, "model")
-
-    return jax.tree.map(leaf, pages)
+    spec = P("data", None, None, "model") if m > 1 else P("data")
+    return jax.tree.map(lambda _: spec, pages)
 
 
 def _param_spec(params, m: int):
@@ -551,7 +544,7 @@ def prefill_chunk_paged_sharded(cfg: ModelConfig, params: dict,
                                 prompt_len: int, mesh):
     """``prefill_chunk_paged`` across every shard of a serving mesh in
     one launch. All array operands carry a leading ``n_shards`` axis
-    (tokens: (n_sh, B, C); page leaves: (n_sh, L, P, page, KV, ...);
+    (tokens: (n_sh, B, C); page leaves: (n_sh, L, P, KV, page, ...);
     tables: (n_sh, B, NBp); start_pos: (n_sh, B)); params replicate
     over "data". Each shard's slice runs the exact single-device chunk
     program, so per-row results are bit-identical to unsharded

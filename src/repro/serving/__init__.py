@@ -3,7 +3,7 @@ from repro.serving.compaction import (
     plan_compaction)
 from repro.serving.engine import (
     BatchedACAREngine, BatchResult, QueuedServeResult, ZooModel,
-    intern_answers, judge_batch)
+    intern_answers, judge_batch, trace_records)
 from repro.serving.jax_backend import JaxModelBackend
 from repro.serving.kv_pool import (
     KVStats, PageAccountingError, PagePool, PagePoolError,
@@ -31,5 +31,5 @@ __all__ = [
     "ShardedStepLoopRunner", "SpanTracer", "StepLoopRunner",
     "StepPlanner", "StepStats", "ZooModel", "bucket_size",
     "dense_tile_slots", "intern_answers", "judge_batch", "pages_for",
-    "plan_compaction",
+    "plan_compaction", "trace_records",
 ]

@@ -864,6 +864,41 @@ class BatchedACAREngine:
                     self._kv_emitted[k] = value
 
 
+def trace_records(run_id: str, tasks: Sequence[Task], res,
+                  member_names: Sequence[str]) -> list:
+    """One ``TraceRecord`` per served task of a ``QueuedServeResult``
+    (admission order): probe samples, member answers, sigma, mode and
+    the final answer — exactly the judge-visible state — so two runs
+    compare on record hashes and, once appended to an
+    ``ArtifactStore``, on the chain head."""
+    from repro.teamllm.fingerprint import prompt_hash, render_prompt
+    from repro.teamllm.trace import (
+        ModelResponse, ProbeSample, TraceRecord)
+
+    out = []
+    for i, task in enumerate(tasks):
+        probe_samples = tuple(
+            ProbeSample(response=txt,
+                        answer=extract(txt, task.kind), cost=0.0)
+            for txt in res.probe_texts[i])
+        responses = tuple(
+            ModelResponse(model=member_names[mi], response="",
+                          answer=a, cost=0.0)
+            for mi, a in enumerate(res.member_answers[i])
+            if a is not None)
+        final = res.final_answers[i]
+        out.append(TraceRecord(
+            run_id=run_id, task_id=task.task_id,
+            benchmark=task.benchmark,
+            prompt_hash=prompt_hash(render_prompt(task.text)),
+            seed=0, sigma=float(res.sigma[i]),
+            mode=MODE_NAMES[int(res.modes[i])],
+            probe_samples=probe_samples, responses=responses,
+            final_answer=final, correct=final == task.gold, cost=0.0,
+            logical_time=i))
+    return out
+
+
 @dataclass
 class QueuedServeResult:
     """Concatenated (admission-order) result of a queued serve run."""

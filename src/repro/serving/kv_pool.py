@@ -31,10 +31,13 @@ The page pytree is heterogeneous — one server serves one *layout*
 (``models.transformer.resolve_layout``), and every leaf keeps the
 page/lane id on axis 1 so one fork/scatter program covers them all:
 
-* ``"dense"`` — ``{k, v}`` of ``(L, P, page_size, KV, Dh)`` in the
-  model dtype (the original layout).
+* ``"dense"`` — ``{k, v}`` of ``(L, P, KV, page_size, Dh)`` in the
+  model dtype. Pages are head-major so that one kv-head's page is a
+  ``(page_size, Dh)`` tile whose two minor dimensions are whole — the
+  block shape the TPU kernels read (``kernels/``); a token-major page
+  would cut the KV axis to a block of 1, which Mosaic refuses.
 * ``"quant"`` — ``{k, v}`` int8 codes plus ``{k_scale, v_scale}``
-  ``(L, P, page_size, KV)`` f32 per-vector scale planes
+  ``(L, P, KV, page_size)`` f32 per-vector scale planes
   (``models.attention.quantize_kv``): Dh + 4 bytes per position
   instead of 2*Dh — roughly 2x the rows per device at the same pool
   bytes.
@@ -83,6 +86,14 @@ class PoolExhausted(PagePoolError):
 
 class PageAccountingError(PagePoolError):
     """Refcount violation: double free or retain of a free page."""
+
+
+def kv_page_shape(cfg: ModelConfig, num_pages: int,
+                  page_size: int) -> Tuple[int, ...]:
+    """Shape of one dense/quant/ring code leaf: ``(L, P, KV, page_size,
+    Dh)``; the quant scale planes drop the trailing Dh."""
+    return (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size,
+            cfg.resolved_head_dim)
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:
@@ -443,8 +454,7 @@ class PagedKVServer:
                 "h": jnp.zeros((cfg.num_layers, num_pages, d_in, n),
                                jnp.float32),
             }
-        shape = (cfg.num_layers, num_pages, self.page_size,
-                 cfg.num_kv_heads, cfg.resolved_head_dim)
+        shape = kv_page_shape(cfg, num_pages, self.page_size)
         dt = jnp.int8 if self.layout == "quant" \
             else jnp.dtype(cfg.dtype)
         pages = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}

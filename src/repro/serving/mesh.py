@@ -18,7 +18,7 @@ the data-parallel half of that story, exercised on CPU via
   model-axis size.
 * **ShardedPagedKVServer** — one model's paged KV state partitioned
   across the mesh. The device page arrays are one global
-  ``(n_shards, L, P, page, KV, Dh)`` array sharded over ``"data"``;
+  ``(n_shards, L, P, KV, page, Dh)`` array sharded over ``"data"``;
   the host-side allocation state is *per shard*: each shard has its
   own ``PagePool`` (shard-local page ids and LIFO free list), its own
   prompt prefix cache, its own scratch region, and its own ``KVStats``
@@ -50,7 +50,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.serving.kv_pool import (
-    KVStats, PagedKVServer, PagePool, PagePoolError)
+    KVStats, PagedKVServer, PagePool, PagePoolError, kv_page_shape)
 
 
 class ServingMesh:
@@ -227,32 +227,27 @@ class ShardedPagedKVServer:
     def _rebuild_device(self, num_pages: int) -> None:
         import jax.numpy as jnp
         cfg = self.cfg
-        shape = (self.n_shards, cfg.num_layers, num_pages,
-                 self.page_size, cfg.num_kv_heads,
-                 cfg.resolved_head_dim)
+        shape = (self.n_shards,) + kv_page_shape(cfg, num_pages,
+                                                 self.page_size)
         dt = jnp.int8 if self.layout == "quant" \
             else jnp.dtype(cfg.dtype)
-        if self.smesh.n_model > 1:
-            # 2-D mesh: each model column holds only its kv-head
-            # slice of every page — per-device page bytes shrink by
-            # the model-axis size, which is exactly where the
-            # capacity gain of tensor parallelism comes from
-            code_spec = P("data", None, None, None, "model", None)
-            scale_spec = P("data", None, None, None, "model")
-        else:
-            code_spec = scale_spec = P("data")
+        # on a 2-D mesh each model column holds only its kv-head slice
+        # of every page (axis 3 of both code and scale leaves) —
+        # per-device page bytes shrink by the model-axis size, which
+        # is exactly where the capacity gain of tensor parallelism
+        # comes from
+        spec = P("data", None, None, "model") \
+            if self.smesh.n_model > 1 else P("data")
 
-        def put(a, spec):
+        def put(a):
             return jax.device_put(
                 a, NamedSharding(self.smesh.mesh, spec))
 
-        pages = {"k": put(jnp.zeros(shape, dt), code_spec),
-                 "v": put(jnp.zeros(shape, dt), code_spec)}
+        pages = {"k": put(jnp.zeros(shape, dt)),
+                 "v": put(jnp.zeros(shape, dt))}
         if self.layout == "quant":
-            pages["k_scale"] = put(jnp.zeros(shape[:-1], jnp.float32),
-                                   scale_spec)
-            pages["v_scale"] = put(jnp.zeros(shape[:-1], jnp.float32),
-                                   scale_spec)
+            pages["k_scale"] = put(jnp.zeros(shape[:-1], jnp.float32))
+            pages["v_scale"] = put(jnp.zeros(shape[:-1], jnp.float32))
         self.pages = pages
 
     # -- fault simulation ----------------------------------------------

@@ -325,40 +325,13 @@ class EngineCompactionReport:
 def _engine_traces(run_id: str, tasks, res, member_names,
                    store: "ArtifactStore"):
     """Materialise one TraceRecord per served task from a
-    QueuedServeResult, so compacted and masked engine runs can be
-    compared through the same hash-chained audit trail the scheduler
-    uses. Probe samples, member answers, sigma, mode, and the final
-    answer — exactly the judge-visible state — are hashed."""
-    from repro.core.extract import extract
-    from repro.core.sigma import MODE_NAMES
-    from repro.teamllm.fingerprint import prompt_hash, render_prompt
-    from repro.teamllm.trace import ModelResponse, ProbeSample, \
-        TraceRecord
-
-    traces = []
-    for i, task in enumerate(tasks):
-        probe_samples = tuple(
-            ProbeSample(response=txt,
-                        answer=extract(txt, task.kind), cost=0.0)
-            for txt in res.probe_texts[i])
-        responses = tuple(
-            ModelResponse(model=member_names[mi], response="",
-                          answer=a, cost=0.0)
-            for mi, a in enumerate(res.member_answers[i])
-            if a is not None)
-        prompt = render_prompt(task.text)
-        final = res.final_answers[i]
-        trace = TraceRecord(
-            run_id=run_id, task_id=task.task_id,
-            benchmark=task.benchmark,
-            prompt_hash=prompt_hash(prompt),
-            seed=0, sigma=float(res.sigma[i]),
-            mode=MODE_NAMES[int(res.modes[i])],
-            probe_samples=probe_samples, responses=responses,
-            final_answer=final, correct=final == task.gold, cost=0.0,
-            logical_time=i)
+    QueuedServeResult (``serving.trace_records``) and append each to
+    ``store``, so two engine runs compare through the same
+    hash-chained audit trail the scheduler uses."""
+    from repro.serving import trace_records
+    traces = trace_records(run_id, tasks, res, member_names)
+    for trace in traces:
         store.append(trace)
-        traces.append(trace)
     return traces
 
 

@@ -49,7 +49,7 @@ def _paged_setup(cfg, batch: int, prompt_len: int, garbage_seed=None):
     (recycled pages must not leak into chunked prefill output)."""
     nbp = -(-prompt_len // PAGE)
     n_pages = batch * nbp + 2
-    shape = (cfg.num_layers, n_pages, PAGE, cfg.num_kv_heads,
+    shape = (cfg.num_layers, n_pages, cfg.num_kv_heads, PAGE,
              cfg.resolved_head_dim)
     if garbage_seed is None:
         k = jnp.zeros(shape, jnp.float32)
@@ -101,10 +101,11 @@ def _written_kv(pages, table, prompt_len, cfg):
     """The prompt-covering slots (the tail page's dead slots past the
     prompt are never read — decode overwrites them position by
     position before attending)."""
-    gathered = pages[:, table]          # (L, B, NBp, PAGE, KV, Dh)
+    gathered = pages[:, table]          # (L, B, NBp, KV, PAGE, Dh)
     layers, b = gathered.shape[0], gathered.shape[1]
-    return gathered.reshape(layers, b, -1, cfg.num_kv_heads,
-                            cfg.resolved_head_dim)[:, :, :prompt_len]
+    return gathered.swapaxes(3, 4).reshape(
+        layers, b, -1, cfg.num_kv_heads,
+        cfg.resolved_head_dim)[:, :, :prompt_len]
 
 
 @pytest.mark.parametrize("prompt_len", [9, 16, 23])
@@ -200,9 +201,9 @@ def test_chunk_kernel_matches_oracle():
     b, c, h, kv, dk, ps, nb = 2, 4, 4, 2, 16, 8, 3
     prompt_len = 21
     q = jnp.asarray(rng.normal(size=(b, c, h, dk)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(b * nb + 1, ps, kv, dk)),
+    kp = jnp.asarray(rng.normal(size=(b * nb + 1, kv, ps, dk)),
                      jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(b * nb + 1, ps, kv, dk)),
+    vp = jnp.asarray(rng.normal(size=(b * nb + 1, kv, ps, dk)),
                      jnp.float32)
     table = jnp.asarray(
         np.arange(b * nb, dtype=np.int32).reshape(b, nb))

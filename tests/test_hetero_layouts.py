@@ -202,8 +202,9 @@ def test_quant_pages_roundtrip_bit_equals_dense_quant(s):
 
     def _gathered(leaf):
         """Row view of the paged bytes over the prompt prefix."""
-        flat = leaf[:, table[:g.nbp]].reshape(
-            (leaf.shape[0], g.nbp * PAGE) + leaf.shape[3:])
+        rows = leaf[:, table[:g.nbp]].swapaxes(2, 3)
+        flat = rows.reshape((leaf.shape[0], g.nbp * PAGE)
+                            + rows.shape[3:])
         return np.asarray(flat[:, :s])
 
     for name in ("k", "v", "k_scale", "v_scale"):
@@ -225,7 +226,7 @@ def test_quant_pages_roundtrip_bit_equals_dense_quant(s):
         pg, off = int(pos) // PAGE, int(pos) % PAGE
         for name in ("k", "v", "k_scale", "v_scale"):
             np.testing.assert_array_equal(
-                np.asarray(pages[name][:, table[pg], off]),
+                np.asarray(pages[name][:, table[pg], :, off]),
                 np.asarray(cache["layers"][name][:, 0, int(pos)]),
                 name)
         tok = jnp.argmax(lg_d, axis=-1).astype(jnp.int32)
@@ -260,7 +261,7 @@ def test_quant_prefix_cache_hit_roundtrips_bitwise():
 
     def _gathered(name):
         leaf = srv.pages[name]
-        flat = np.asarray(leaf[:, np.asarray(row_pages)])
+        flat = np.asarray(leaf[:, np.asarray(row_pages)]).swapaxes(2, 3)
         flat = flat.reshape((leaf.shape[0], len(row_pages) * PAGE)
                             + flat.shape[3:])
         return flat[:, :s]

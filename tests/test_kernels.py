@@ -243,8 +243,8 @@ def test_paged_decode_attention_quant_sweep(b, h, kv, dk, ps, nb):
     ks = jax.random.split(jax.random.PRNGKey(b * ps + nb), 3)
     n_pages = b * nb + 2
     q = jax.random.normal(ks[0], (b, h, dk))
-    kq, kscale = _quantized(ks[1], (n_pages, ps, kv, dk))
-    vq, vscale = _quantized(ks[2], (n_pages, ps, kv, dk))
+    kq, kscale = _quantized(ks[1], (n_pages, kv, ps, dk))
+    vq, vscale = _quantized(ks[2], (n_pages, kv, ps, dk))
     table = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
     lengths = jnp.int32(nb * ps) - jnp.arange(b, dtype=jnp.int32) * 5 \
         - 1
@@ -264,8 +264,8 @@ def test_paged_decode_attention_quant_stale_pages_masked():
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     b, h, kv, dk, ps, nb = 2, 4, 2, 64, 8, 4
     q = jax.random.normal(ks[0], (b, h, dk))
-    kq, kscale = _quantized(ks[1], (b * nb, ps, kv, dk))
-    vq, vscale = _quantized(ks[2], (b * nb, ps, kv, dk))
+    kq, kscale = _quantized(ks[1], (b * nb, kv, ps, dk))
+    vq, vscale = _quantized(ks[2], (b * nb, kv, ps, dk))
     table = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
     lengths = jnp.array([ps + 3, 2 * ps], jnp.int32)   # rows mid-page
     out1 = kernel(q, kq, kscale, vq, vscale, table, lengths,
@@ -274,8 +274,8 @@ def test_paged_decode_attention_quant_stale_pages_masked():
     stale = jnp.concatenate([table[0, 2:], table[1, 2:]])
     poison_k = kq.at[stale].set(127)
     poison_s = kscale.at[stale].set(1e6)
-    poison_k = poison_k.at[table[0, 1], 3:].set(-127)
-    poison_s = poison_s.at[table[0, 1], 3:].set(1e6)
+    poison_k = poison_k.at[table[0, 1], :, 3:].set(-127)
+    poison_s = poison_s.at[table[0, 1], :, 3:].set(1e6)
     out2 = kernel(q, poison_k, poison_s, vq, vscale, table, lengths,
                   interpret=True)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
@@ -314,8 +314,8 @@ def test_ops_quant_dispatch_cpu_is_bitwise_oracle():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
     ps, nb = 8, 4
-    kpq, kps_ = _quantized(ks[1], (b * nb, ps, kv, dk))
-    vpq, vps_ = _quantized(ks[2], (b * nb, ps, kv, dk))
+    kpq, kps_ = _quantized(ks[1], (b * nb, kv, ps, dk))
+    vpq, vps_ = _quantized(ks[2], (b * nb, kv, ps, dk))
     table = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
     lengths = jnp.array([ps + 3, 2 * ps], jnp.int32)
     pout = ops.paged_decode_attention_quant(

@@ -18,8 +18,8 @@ def tol_for(dtype):
 def _paged_case(key, b, h, kv, dk, ps, nb, n_pages, dtype):
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (b, h, dk), dtype)
-    k_pages = jax.random.normal(ks[1], (n_pages, ps, kv, dk), dtype)
-    v_pages = jax.random.normal(ks[2], (n_pages, ps, kv, dk), dtype)
+    k_pages = jax.random.normal(ks[1], (n_pages, kv, ps, dk), dtype)
+    v_pages = jax.random.normal(ks[2], (n_pages, kv, ps, dk), dtype)
     # distinct pages per row: a permutation slice, like the pool yields
     rng = np.random.default_rng(b * nb + h)
     bt = jnp.asarray(rng.permutation(n_pages)[:b * nb].reshape(b, nb),
@@ -35,8 +35,8 @@ def test_paged_ref_matches_dense_ref_rowwise():
                                 8, 6, 32, jnp.float32)
     lengths = jnp.asarray([48, 17, 1], jnp.int32)
     got = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths)
-    kc = kp[bt].reshape(3, -1, 2, 64)
-    vc = vp[bt].reshape(3, -1, 2, 64)
+    kc = ref.gather_pages(kp, bt)
+    vc = ref.gather_pages(vp, bt)
     for r in range(3):
         want = ref.decode_attention_ref(q[r:r + 1], kc[r:r + 1],
                                         vc[r:r + 1], lengths[r])
@@ -57,7 +57,7 @@ def test_paged_ref_ignores_unmapped_pages():
     for r in range(2):
         for j, page in enumerate(np.asarray(bt)[r]):
             mask = pos[j * 8:(j + 1) * 8] >= int(lengths[r])
-            kp2[page, mask] = 99.0
+            kp2[page, :, mask] = 99.0
     out2 = ref.paged_decode_attention_ref(q, jnp.asarray(kp2), vp, bt,
                                           lengths)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
@@ -109,8 +109,8 @@ def test_paged_kernel_shared_prefix_rows():
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     b, h, kv, dk, ps = 4, 4, 2, 64, 8
     q = jax.random.normal(ks[0], (b, h, dk))
-    kp = jax.random.normal(ks[1], (16, ps, kv, dk))
-    vp = jax.random.normal(ks[2], (16, ps, kv, dk))
+    kp = jax.random.normal(ks[1], (16, kv, ps, dk))
+    vp = jax.random.normal(ks[2], (16, kv, ps, dk))
     # all rows share pages [0, 1]; private third page per row
     bt = jnp.asarray([[0, 1, 2 + r] for r in range(b)], jnp.int32)
     lengths = jnp.asarray([17, 18, 19, 20], jnp.int32)
