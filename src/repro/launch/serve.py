@@ -24,9 +24,11 @@ from __future__ import annotations
 
 import argparse
 import collections
+import contextlib
 from typing import Dict, List, Optional, Sequence
 
 import jax
+import numpy as np
 
 from repro.checkpoint import restore_checkpoint
 from repro.configs.acar import ACARConfig
@@ -90,6 +92,27 @@ def build_zoo(archs: Sequence[str], train_steps: int, seed: int = 0,
                               lr=2e-3, seed=seed + i, verbose=False)
         zoo.append(ZooModel(name=spec, cfg=cfg, params=prm))
     return zoo
+
+
+def request_walls(step) -> dict:
+    """Per-request wall times of a step-loop run, on its host spans'
+    clock (``StepStats.wall``, ``StepStats.host_spans``): admission to
+    route (median) and to retirement (median and 90th percentile), in
+    ms, and the mean number of decode launches that served a request
+    (the ``acar.decode.launch`` spans whose rows hold it). A row that
+    never reached a stamp (an aborted one) counts in none of the times."""
+    walls = list(step.wall.values())
+    rode = collections.Counter(
+        i for sp in step.host_spans if sp.name == "acar.decode.launch"
+        for i in set(sp.rows))
+    done = [t - a for a, _, t in walls]
+    return {
+        "time_to_route_p50_ms":
+            float(np.nanmedian([r - a for a, r, _ in walls])) * 1e3,
+        "request_p50_ms": float(np.nanmedian(done)) * 1e3,
+        "request_p90_ms": float(np.nanpercentile(done, 90)) * 1e3,
+        "decode_launches_per_request": sum(rode.values()) / len(walls),
+    }
 
 
 def serve(tasks: Sequence[Task], probe: ZooModel,
@@ -165,6 +188,9 @@ def serve(tasks: Sequence[Task], probe: ZooModel,
         out["probe_prefill_reduction"] = cs.probe_prefill_reduction
     if scheduler:
         out["batch_sizes"] = res.batch_sizes
+    step = getattr(res, "step", None)
+    if step is not None and step.wall:
+        out.update(request_walls(step))
     if verbose:
         print(f"served {len(tasks)} tasks in {res.wall_ms:.0f} ms")
         print(f"accuracy          : {out['accuracy']:.3f}")
@@ -184,6 +210,14 @@ def serve(tasks: Sequence[Task], probe: ZooModel,
                 print(f"step loop         : {res.step.ticks} ticks, "
                       f"{res.step.invocations} program launches, "
                       f"{res.step.prefill_chunks} prefill chunks")
+            if "request_p50_ms" in out:
+                print(f"requests          : routed in "
+                      f"{out['time_to_route_p50_ms']:.1f} ms, retired "
+                      f"in {out['request_p50_ms']:.1f} ms (p50; p90 "
+                      f"{out['request_p90_ms']:.1f} ms) after "
+                      f"admission, "
+                      f"{out['decode_launches_per_request']:.1f} "
+                      f"decode launches each")
             print(res.metrics.render())
     if tracer is not None and getattr(res, "spans", None) is not None:
         out["spans"] = len(res.spans)
@@ -263,6 +297,11 @@ def main(argv=None):
                          "the hash-chained span JSONL here (span "
                          "structure is bit-identical run to run; "
                          "wall-times ride the non-hashed side channel)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of the serve "
+                         "into DIR: the step loop's acar.* host spans "
+                         "beside the device's programs (TensorBoard "
+                         "or Perfetto reads it)")
     ap.add_argument("--lineage", default=None, metavar="TASK_ID",
                     help="after serving, walk the PROV lineage of this "
                          "task's final answer (answer -> judge -> "
@@ -282,11 +321,13 @@ def main(argv=None):
                       probe_temperature=args.probe_temperature,
                       seed=args.seed)
     tasks = arithmetic_suite(args.tasks, seed=args.seed + 99)
-    serve(tasks, probe, ensemble, acfg,
-          scheduler=args.scheduler, step_loop=args.step_loop,
-          batch_size=args.batch_size, data_shards=args.shards,
-          megastep=args.megastep, trace_path=args.trace,
-          lineage_task=args.lineage)
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        serve(tasks, probe, ensemble, acfg,
+              scheduler=args.scheduler, step_loop=args.step_loop,
+              batch_size=args.batch_size, data_shards=args.shards,
+              megastep=args.megastep, trace_path=args.trace,
+              lineage_task=args.lineage)
 
 
 if __name__ == "__main__":

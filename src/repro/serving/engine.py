@@ -722,87 +722,109 @@ class BatchedACAREngine:
         retire, every fault-path event), structure bit-identical run
         to run while wall-times ride the non-hashed side channel —
         arming it cannot perturb record hashes or chain heads
-        (``simulate.py --obs`` proves it). Zero-cost when unset."""
+        (``simulate.py --obs`` proves it). Zero-cost when unset.
+
+        Every call also keeps wall-clock host spans of the step loop
+        (``StepStats.host_spans``, ``serving/tracing.HostSpans``: one
+        ``jax.profiler.TraceAnnotation`` each, on the device trace's
+        clock when a profiler records) and per-request wall stamps
+        (``StepStats.wall``). Neither is hashed or journaled."""
         from repro.serving.scheduler import StepPlanner
         from repro.serving.step_loop import (
             ShardedStepLoopRunner, StepLoopRunner)
+        from repro.serving.tracing import HostSpans
         t0 = time.perf_counter()
-        injector = None
-        if faults is not None:
-            from repro.serving.faults import FaultInjector
-            injector = FaultInjector(faults)
-        journal = None
-        if journal_path is not None:
-            from repro.serving.journal import StepJournal
-            journal = StepJournal(journal_path, injector=injector)
-        queue = AdmissionQueue(policy)
-        for t in tasks:
-            queue.submit(t)
-        if megastep == "auto":
-            planner = StepPlanner(
-                chunk_tokens=chunk_tokens,
-                max_active_rows=max_active_rows
-                or policy.max_batch_size,
-                megastep=16, megastep_auto=True)
-        else:
-            planner = StepPlanner(
-                chunk_tokens=chunk_tokens,
-                max_active_rows=max_active_rows
-                or policy.max_batch_size,
-                megastep=megastep)
         metrics = PromCounters()
-        if data_shards is None:
-            if model_shards != 1:
-                raise ValueError(
-                    "model_shards > 1 requires the sharded loop: "
-                    "pass data_shards as well")
-            runner = StepLoopRunner(self, queue, planner, metrics,
-                                    faults=injector, journal=journal,
-                                    recovered=recovered,
-                                    tracer=tracer)
-        else:
-            from repro.serving.mesh import ServingMesh
-            runner = ShardedStepLoopRunner(
-                self, queue, planner,
-                ServingMesh(data=data_shards, model=model_shards),
-                metrics, faults=injector, journal=journal,
-                recovered=recovered, tracer=tracer)
-        step_stats = runner.run()
-        # the sharded runner's servers live outside self._kv_servers:
-        # emit the pool gauges / reuse counters from whichever set
-        # actually served the run (plain runner: the engine's own)
-        self._emit_kv_metrics(metrics, kv=runner.kv_stats())
+        # wall-clock host spans of this call (StepStats.host_spans);
+        # they feed acar_span_duration only while the audit tracer is
+        # armed
+        spans = HostSpans(
+            metrics if getattr(tracer, "armed", False) else None)
+        with spans.span("acar.call"):
+            with spans.span("acar.call.setup"):
+                injector = None
+                if faults is not None:
+                    from repro.serving.faults import FaultInjector
+                    injector = FaultInjector(faults)
+                journal = None
+                if journal_path is not None:
+                    from repro.serving.journal import StepJournal
+                    journal = StepJournal(journal_path,
+                                          injector=injector)
+                queue = AdmissionQueue(policy)
+                for t in tasks:
+                    queue.submit(t)
+                if megastep == "auto":
+                    planner = StepPlanner(
+                        chunk_tokens=chunk_tokens,
+                        max_active_rows=max_active_rows
+                        or policy.max_batch_size,
+                        megastep=16, megastep_auto=True)
+                else:
+                    planner = StepPlanner(
+                        chunk_tokens=chunk_tokens,
+                        max_active_rows=max_active_rows
+                        or policy.max_batch_size,
+                        megastep=megastep)
+                if data_shards is None:
+                    if model_shards != 1:
+                        raise ValueError(
+                            "model_shards > 1 requires the sharded "
+                            "loop: pass data_shards as well")
+                    runner = StepLoopRunner(
+                        self, queue, planner, metrics, faults=injector,
+                        journal=journal, recovered=recovered,
+                        tracer=tracer, spans=spans)
+                else:
+                    from repro.serving.mesh import ServingMesh
+                    runner = ShardedStepLoopRunner(
+                        self, queue, planner,
+                        ServingMesh(data=data_shards,
+                                    model=model_shards),
+                        metrics, faults=injector, journal=journal,
+                        recovered=recovered, tracer=tracer,
+                        spans=spans)
+            step_stats = runner.run()
+            with spans.span("acar.call.finish"):
+                # the sharded runner's servers live outside
+                # self._kv_servers: emit the pool gauges / reuse
+                # counters from whichever set actually served the run
+                # (plain runner: the engine's own)
+                self._emit_kv_metrics(metrics, kv=runner.kv_stats())
 
-        rows = [runner.done_rows[i] for i in range(len(tasks))]
-        saved = sum(
-            len(self.ensemble) - sum(
-                1 for mi in range(len(self.ensemble))
-                if r.mode >= (1 if mi < self.acfg.arena_lite_size
-                              else 2))
-            for r in rows)
-        admit_ticks: Dict[int, int] = {}
-        for a, (_, adm, _) in sorted(step_stats.timeline.items()):
-            admit_ticks[adm] = admit_ticks.get(adm, 0) + 1
-        return QueuedServeResult(
-            sigma=np.asarray([r.sigma for r in rows], np.float32),
-            modes=np.asarray([r.mode for r in rows], np.int32),
-            final_answers=[r.final_answer for r in rows],
-            batch_sizes=[v for _, v in sorted(admit_ticks.items())],
-            ensemble_calls_saved=saved,
-            wall_ms=(time.perf_counter() - t0) * 1e3,
-            metrics=metrics,
-            probe_texts=[r.probe_texts or [] for r in rows],
-            member_answers=[r.member_answers or
-                            [None] * len(self.ensemble)
-                            for r in rows],
-            kv=runner.kv_stats() or None,
-            step=step_stats,
-            faults=runner.fault_events or None,
-            restored_rows=step_stats.restored,
-            spans=runner.tracer.records()
-            if runner.tracer is not None else None,
-            span_head=runner.tracer.flush()
-            if runner.tracer is not None else None)
+                rows = [runner.done_rows[i] for i in range(len(tasks))]
+                lite = self.acfg.arena_lite_size
+                saved = sum(
+                    len(self.ensemble) - sum(
+                        1 for mi in range(len(self.ensemble))
+                        if r.mode >= (1 if mi < lite else 2))
+                    for r in rows)
+                admit_ticks: Dict[int, int] = {}
+                for a, (_, adm, _) in sorted(step_stats.timeline.items()):
+                    admit_ticks[adm] = admit_ticks.get(adm, 0) + 1
+                res = QueuedServeResult(
+                    sigma=np.asarray([r.sigma for r in rows],
+                                     np.float32),
+                    modes=np.asarray([r.mode for r in rows], np.int32),
+                    final_answers=[r.final_answer for r in rows],
+                    batch_sizes=[v for _, v
+                                 in sorted(admit_ticks.items())],
+                    ensemble_calls_saved=saved,
+                    wall_ms=(time.perf_counter() - t0) * 1e3,
+                    metrics=metrics,
+                    probe_texts=[r.probe_texts or [] for r in rows],
+                    member_answers=[r.member_answers or
+                                    [None] * len(self.ensemble)
+                                    for r in rows],
+                    kv=runner.kv_stats() or None,
+                    step=step_stats,
+                    faults=runner.fault_events or None,
+                    restored_rows=step_stats.restored,
+                    spans=runner.tracer.records()
+                    if runner.tracer is not None else None,
+                    span_head=runner.tracer.flush()
+                    if runner.tracer is not None else None)
+        return res
 
     def recover(self, tasks: Sequence[Task],
                 policy: MicroBatchPolicy = MicroBatchPolicy(), *,

@@ -5,8 +5,8 @@ queued serving path; rendering follows the Prometheus text exposition
 format with deterministic ordering. Counters accumulate via ``inc``;
 gauges (``set_gauge``) hold the last observed value — used for
 per-wave occupancy readings like compaction bucket fill; histograms
-(``observe``) bucket wall-clock samples — used for per-phase span
-latencies (``acar_span_duration{phase}``) and decode-launch times.
+(``observe``) bucket wall-clock samples — used for the step loop's
+host span durations (``acar_span_duration{phase}``).
 
 A metric name owns one kind for the registry's lifetime: re-using a
 counter name as a gauge (or any other cross-kind collision) raises

@@ -88,11 +88,14 @@ from repro.serving.metrics import (
     SHARD_STEALS, STEP_REQUEUES)
 from repro.serving.queue import AdmissionQueue, Request
 from repro.serving.scheduler import StepPlanner
+from repro.serving.tracing import HostSpans
 from repro.teamllm.spans import make_trace_id
 from repro.teamllm.trace import fault_record
 
 PHASES = ("prefill", "probe_decode", "route_pending",
           "ensemble_decode", "done")
+# StepStats.wall before a stamp: (admitted, routed, retired)
+_UNSTAMPED = (float("nan"),) * 3
 
 
 # ----------------------------------------------------------------------
@@ -196,6 +199,13 @@ class StepStats:
     # per admission index: (arrival_tick, admitted_tick, retired_tick)
     timeline: Dict[int, Tuple[int, int, int]] = field(
         default_factory=dict)
+    # host wall clock (time.perf_counter), never journaled or hashed:
+    # the run's host spans (serving/tracing.HostSpan, opening order)
+    # and per admission index (admitted, routed, retired), NaN where a
+    # row never got there (an abort before its route)
+    host_spans: List = field(default_factory=list)
+    wall: Dict[int, Tuple[float, float, float]] = field(
+        default_factory=dict)
 
     def latencies(self) -> np.ndarray:
         """Virtual-clock task latency (retire - arrival) per task."""
@@ -213,7 +223,7 @@ class StepLoopRunner:
                  faults: Optional[FaultInjector] = None,
                  journal=None,
                  recovered: Optional[Dict[int, dict]] = None,
-                 tracer=None):
+                 tracer=None, spans: Optional[HostSpans] = None):
         self.eng = engine
         self.queue = queue
         self.planner = planner
@@ -240,6 +250,12 @@ class StepLoopRunner:
         self.tracer = tracer if (tracer is not None
                                  and getattr(tracer, "armed", False)) \
             else None
+        # wall-clock host spans are always on; they feed the
+        # operator's acar_span_duration histogram only while the audit
+        # tracer is armed
+        self.spans = spans if spans is not None else HostSpans(
+            self.metrics if self.tracer is not None else None)
+        self.stats.host_spans = self.spans.records
         # escalated full-arena rows awaiting on-capacity
         # leave-one-out attribution (drained on idle ticks)
         self._attrib_queue: List[_Row] = []
@@ -319,6 +335,13 @@ class StepLoopRunner:
         pages = min(pages, row.reserved)
         row.reserved -= pages
         self._reserved -= pages
+
+    def _stamp(self, row: _Row, slot: int) -> None:
+        """``StepStats.wall`` for ``row``: slot 0 admitted, 1 routed,
+        2 retired, on the host spans' clock."""
+        w = list(self.stats.wall.get(row.admission, _UNSTAMPED))
+        w[slot] = time.perf_counter()
+        self.stats.wall[row.admission] = tuple(w)
 
     # -- span tracing --------------------------------------------------
     def _trace_id(self, row: _Row) -> str:
@@ -560,6 +583,7 @@ class StepLoopRunner:
         row.phase = "prefill"
         self._unreserve(row, row.reserved)
         self.stats.timeline.pop(row.admission, None)
+        self.stats.wall.pop(row.admission, None)
 
     def _try_begin_prefill(self, row: _Row) -> bool:
         """Admission-time allocation with ``PoolExhausted`` rollback:
@@ -677,6 +701,7 @@ class StepLoopRunner:
             self._reserved += row.reserved
             self.stats.timeline[row.admission] = (
                 req.arrival_time, self.now, -1)
+            self._stamp(row, 0)
             if self.tracer is not None:
                 self.tracer.span("admit", self._trace_id(row),
                                  self.now, prompt_tokens=s,
@@ -811,68 +836,65 @@ class StepLoopRunner:
         _, c, s = key
         if c < 0:
             return self._run_one_shot_prefill_group(key, items)
-        t0 = time.perf_counter() if self.tracer is not None else 0.0
         srv = items[0][0]
-        ps = srv.page_size
-        nbp = pages_for(s, ps)
+        nbp = pages_for(s, srv.page_size)
         rows = sorted(items, key=lambda it: it[1].admission)
         bucket = self.planner.decode_bucket(len(rows))
-        tokens = np.empty((bucket, c), np.int32)
-        tables = np.empty((bucket, nbp), np.int32)
-        starts = np.zeros(bucket, np.int32)
-        for i in range(bucket):
-            srv_i, row, mx = rows[min(i, len(rows) - 1)]
-            target = mx if mx is not None else row
-            starts[i] = target.prefill_pos
-            tokens[i] = row.ids[starts[i]:starts[i] + c]
-            if i < len(rows):
-                tables[i, :target.shared.size] = target.shared
-                if target.tail is not None:
-                    tables[i, -1] = target.tail
-            else:
-                tables[i] = srv._scratch[:nbp]
-        zm = self._server_model(srv)
-        lg, srv.pages = S.prefill_chunk_paged(
-            zm.cfg, zm.params, jnp.asarray(tokens), srv.pages,
-            jnp.asarray(tables), jnp.asarray(starts),
-            prompt_len=s)
-        srv.stats.prefill_tokens_computed += bucket * c
-        srv.stats.prefill_chunks += 1
-        self.stats.prefill_chunks += 1
-        self.metrics.inc("acar_prefill_chunks_total",
-                         model=srv.stats.model,
-                         help="chunked-prefill device programs run")
-        self.stats.launches += 1
-        # chunk-final logits stay on device in the model's native
-        # dtype (a bf16 member's lane state is bf16 end-to-end; the
-        # old np.float32 host cast silently widened it while the
-        # device path stayed bf16)
-        for i, (srv_i, row, mx) in enumerate(rows):
-            target = mx if mx is not None else row
-            start0 = int(starts[i])
-            target.prefill_pos = start0 + c
-            sid = None
-            if self.tracer is not None:
-                sid = self.tracer.span(
-                    "prefill_chunk", self._trace_id(row), self.now,
-                    key=None if mx is None else ("m", mx.member),
-                    model=srv.stats.model, start=start0, tokens=c)
-            if target.prefill_pos == s:
-                target.logits0 = lg[i]
-                # publish to the server's prefix cache (cost-aware
-                # eviction keys off tokens-saved-per-page)
-                srv._prefix_insert(row.ids.tobytes(), target.shared,
-                                   target.tail, lg[i], tokens=s)
-                if sid is not None:
-                    self.tracer.kv_insert(
-                        srv.stats.model,
-                        hashlib.sha256(row.ids.tobytes()).hexdigest(),
-                        self._trace_id(row), sid)
-        if self.tracer is not None:
-            self.metrics.observe(
-                "acar_span_duration", time.perf_counter() - t0,
-                phase="prefill",
-                help="host wall seconds per traced lifecycle phase")
+        with self.spans.span("acar.prefill",
+                             [it[1].admission for it in rows],
+                             model=srv.stats.model, bucket=bucket,
+                             lanes=len(rows)):
+            tokens = np.empty((bucket, c), np.int32)
+            tables = np.empty((bucket, nbp), np.int32)
+            starts = np.zeros(bucket, np.int32)
+            for i in range(bucket):
+                srv_i, row, mx = rows[min(i, len(rows) - 1)]
+                target = mx if mx is not None else row
+                starts[i] = target.prefill_pos
+                tokens[i] = row.ids[starts[i]:starts[i] + c]
+                if i < len(rows):
+                    tables[i, :target.shared.size] = target.shared
+                    if target.tail is not None:
+                        tables[i, -1] = target.tail
+                else:
+                    tables[i] = srv._scratch[:nbp]
+            zm = self._server_model(srv)
+            lg, srv.pages = S.prefill_chunk_paged(
+                zm.cfg, zm.params, jnp.asarray(tokens), srv.pages,
+                jnp.asarray(tables), jnp.asarray(starts),
+                prompt_len=s)
+            srv.stats.prefill_tokens_computed += bucket * c
+            srv.stats.prefill_chunks += 1
+            self.stats.prefill_chunks += 1
+            self.metrics.inc("acar_prefill_chunks_total",
+                             model=srv.stats.model,
+                             help="chunked-prefill device programs run")
+            self.stats.launches += 1
+            # chunk-final logits stay on device in the model's native
+            # dtype (a bf16 member's lane state is bf16 end-to-end; the
+            # old np.float32 host cast silently widened it while the
+            # device path stayed bf16)
+            for i, (srv_i, row, mx) in enumerate(rows):
+                target = mx if mx is not None else row
+                start0 = int(starts[i])
+                target.prefill_pos = start0 + c
+                sid = None
+                if self.tracer is not None:
+                    sid = self.tracer.span(
+                        "prefill_chunk", self._trace_id(row), self.now,
+                        key=None if mx is None else ("m", mx.member),
+                        model=srv.stats.model, start=start0, tokens=c)
+                if target.prefill_pos == s:
+                    target.logits0 = lg[i]
+                    # publish to the server's prefix cache (cost-aware
+                    # eviction keys off tokens-saved-per-page)
+                    srv._prefix_insert(row.ids.tobytes(), target.shared,
+                                       target.tail, lg[i], tokens=s)
+                    if sid is not None:
+                        self.tracer.kv_insert(
+                            srv.stats.model,
+                            hashlib.sha256(row.ids.tobytes()).hexdigest(),
+                            self._trace_id(row), sid)
         return 1
 
     def _run_one_shot_prefill_group(self, key, items) -> int:
@@ -884,61 +906,59 @@ class StepLoopRunner:
         layout choice never moves the latency accounting."""
         import jax.numpy as jnp
         _, _, s = key
-        t0 = time.perf_counter() if self.tracer is not None else 0.0
         srv = items[0][0]
         g = self._geometry(srv, s)
         rows = sorted(items, key=lambda it: it[1].admission)
         bucket = self.planner.decode_bucket(len(rows))
-        tokens = np.zeros((bucket, s), np.int32)
-        tables = np.empty((bucket, g.nbp), np.int32)
-        for i in range(bucket):
-            if i < len(rows):
-                srv_i, row, mx = rows[i]
-                target = mx if mx is not None else row
-                tokens[i] = row.ids
-                tables[i, :target.shared.size] = target.shared
-                if target.tail is not None:
-                    tables[i, -1] = target.tail
+        with self.spans.span("acar.prefill",
+                             [it[1].admission for it in rows],
+                             model=srv.stats.model, bucket=bucket,
+                             lanes=len(rows)):
+            tokens = np.zeros((bucket, s), np.int32)
+            tables = np.empty((bucket, g.nbp), np.int32)
+            for i in range(bucket):
+                if i < len(rows):
+                    srv_i, row, mx = rows[i]
+                    target = mx if mx is not None else row
+                    tokens[i] = row.ids
+                    tables[i, :target.shared.size] = target.shared
+                    if target.tail is not None:
+                        tables[i, -1] = target.tail
+                else:
+                    # pad rows prefill zeros into scratch pages
+                    tables[i] = srv._scratch[:g.nbp]
+            zm = self._server_model(srv)
+            if srv.layout == "lanes":
+                lg, srv.pages = S.prefill_lanes(
+                    zm.cfg, zm.params, jnp.asarray(tokens), srv.pages,
+                    jnp.asarray(tables[:, 0]))
             else:
-                # pad rows prefill zeros into scratch pages
-                tables[i] = srv._scratch[:g.nbp]
-        zm = self._server_model(srv)
-        if srv.layout == "lanes":
-            lg, srv.pages = S.prefill_lanes(
-                zm.cfg, zm.params, jnp.asarray(tokens), srv.pages,
-                jnp.asarray(tables[:, 0]))
-        else:
-            cl = g.cache_len if srv.layout == "ring" else None
-            lg, srv.pages = S.prefill_paged(
-                zm.cfg, zm.params, jnp.asarray(tokens), srv.pages,
-                jnp.asarray(tables), cache_len=cl)
-        srv.stats.prefill_tokens_computed += bucket * s
-        self.metrics.inc("acar_prefill_oneshot_total",
-                         model=srv.stats.model,
-                         help="one-shot prefill device programs run "
-                              "for non-chunkable page layouts")
-        self.stats.launches += 1
-        for i, (srv_i, row, mx) in enumerate(rows):
-            target = mx if mx is not None else row
-            target.prefill_pos = s
-            target.logits0 = lg[i]
-            srv._prefix_insert(row.ids.tobytes(), target.shared,
-                               target.tail, lg[i], tokens=s)
-            if self.tracer is not None:
-                sid = self.tracer.span(
-                    "prefill_chunk", self._trace_id(row), self.now,
-                    key=None if mx is None else ("m", mx.member),
-                    model=srv.stats.model, start=0, tokens=s,
-                    oneshot=1)
-                self.tracer.kv_insert(
-                    srv.stats.model,
-                    hashlib.sha256(row.ids.tobytes()).hexdigest(),
-                    self._trace_id(row), sid)
-        if self.tracer is not None:
-            self.metrics.observe(
-                "acar_span_duration", time.perf_counter() - t0,
-                phase="prefill",
-                help="host wall seconds per traced lifecycle phase")
+                cl = g.cache_len if srv.layout == "ring" else None
+                lg, srv.pages = S.prefill_paged(
+                    zm.cfg, zm.params, jnp.asarray(tokens), srv.pages,
+                    jnp.asarray(tables), cache_len=cl)
+            srv.stats.prefill_tokens_computed += bucket * s
+            self.metrics.inc("acar_prefill_oneshot_total",
+                             model=srv.stats.model,
+                             help="one-shot prefill device programs run "
+                                  "for non-chunkable page layouts")
+            self.stats.launches += 1
+            for i, (srv_i, row, mx) in enumerate(rows):
+                target = mx if mx is not None else row
+                target.prefill_pos = s
+                target.logits0 = lg[i]
+                srv._prefix_insert(row.ids.tobytes(), target.shared,
+                                   target.tail, lg[i], tokens=s)
+                if self.tracer is not None:
+                    sid = self.tracer.span(
+                        "prefill_chunk", self._trace_id(row), self.now,
+                        key=None if mx is None else ("m", mx.member),
+                        model=srv.stats.model, start=0, tokens=s,
+                        oneshot=1)
+                    self.tracer.kv_insert(
+                        srv.stats.model,
+                        hashlib.sha256(row.ids.tobytes()).hexdigest(),
+                        self._trace_id(row), sid)
         return self.planner.chunk_count(s)
 
     def _server_model(self, srv: PagedKVServer):
@@ -1013,7 +1033,6 @@ class StepLoopRunner:
     def _run_decode_group(self, key, items) -> int:
         import jax.numpy as jnp
         _, temperature, cache_len = key
-        t0 = time.perf_counter() if self.tracer is not None else 0.0
         srv = items[0][0]
         nb = srv.table_width(cache_len - self.max_new, self.max_new)
         ordered = sorted(items, key=lambda it: (it[1].admission,
@@ -1029,86 +1048,87 @@ class StepLoopRunner:
         bucket = self.planner.decode_bucket(len(lanes))
         k = len(lanes)
         kl = self._megastep_span(lanes)
-        tables = np.empty((bucket, nb), np.int32)
-        pos = np.empty(bucket, np.int32)
-        keys = np.empty((bucket, 2), np.uint32)
-        steps = np.empty(bucket, np.int32)
-        done = np.zeros(bucket, bool)
-        for i in range(bucket):
-            lane = lanes[min(i, k - 1)]
-            tables[i] = lane.block_table if i < k else srv._scratch[:nb]
-            pos[i] = cache_len - self.max_new + lane.steps
-            keys[i] = lane.row_key
-            steps[i] = lane.steps
-            # pad rows emit pads into scratch; a lane a quarantine
-            # dropped earlier this tick decodes masked (its pages are
-            # already released)
-            done[i] = i >= k or lane.done
-        # lane logits never left the device: stacking slices of the
-        # previous megastep's next_logits is a device-side gather
-        logits = jnp.stack([lanes[min(i, k - 1)].logits
-                            for i in range(bucket)])
-        zm = self._server_model(srv)
-        (emits, dones, next_logits,
-         srv.pages) = S.decode_megastep_rows(
-            zm.cfg, zm.params, logits, srv.pages,
-            jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(keys),
-            jnp.asarray(steps), jnp.asarray(done), n_ticks=kl,
-            cache_len=cache_len, temperature=temperature,
-            eos_id=tok.EOS, pad_id=tok.PAD)
-        # the megastep's only host pulls: (K, B) token ids + done bits
-        emits = np.asarray(emits)
-        dones = np.asarray(dones)
+        span = self.spans.span
+        f = dict(model=srv.stats.model, bucket=bucket, lanes=k,
+                 ticks=kl)
+        with span("acar.decode.stage", **f):
+            tables = np.empty((bucket, nb), np.int32)
+            pos = np.empty(bucket, np.int32)
+            keys = np.empty((bucket, 2), np.uint32)
+            steps = np.empty(bucket, np.int32)
+            done = np.zeros(bucket, bool)
+            for i in range(bucket):
+                lane = lanes[min(i, k - 1)]
+                tables[i] = lane.block_table if i < k \
+                    else srv._scratch[:nb]
+                pos[i] = cache_len - self.max_new + lane.steps
+                keys[i] = lane.row_key
+                steps[i] = lane.steps
+                # pad rows emit pads into scratch; a lane a quarantine
+                # dropped earlier this tick decodes masked (its pages
+                # are already released)
+                done[i] = i >= k or lane.done
+            # lane logits never left the device: stacking slices of the
+            # previous megastep's next_logits is a device-side gather
+            logits = jnp.stack([lanes[min(i, k - 1)].logits
+                                for i in range(bucket)])
+            operands = [jnp.asarray(a)
+                        for a in (tables, pos, keys, steps, done)]
+            zm = self._server_model(srv)
+        with span("acar.decode.launch",
+                  [it[1].admission for it in ordered], **f):
+            (emits, dones, next_logits,
+             srv.pages) = S.decode_megastep_rows(
+                zm.cfg, zm.params, logits, srv.pages, *operands,
+                n_ticks=kl, cache_len=cache_len,
+                temperature=temperature, eos_id=tok.EOS,
+                pad_id=tok.PAD)
+        with span("acar.decode.wait", **f):
+            # the megastep's only host pulls: (K, B) ids + done bits
+            emits = np.asarray(emits)
+            dones = np.asarray(dones)
         self.stats.launches += 1
         self.stats.decode_h2d += 5     # tables, pos, keys, steps, done
         self.stats.decode_d2h += 2     # emits, dones
-        if (self.injector is not None
-                and all(l.tag >= 100 for l in lanes)
-                and not np.isfinite(np.asarray(
-                    next_logits[:k], np.float32)).all()):
-            # genuine non-finite member logits: discard the launch
-            # (lane state is untouched) and quarantine — only checked
-            # while an injector is attached, so the fault-free path
-            # never pays the extra device sync
-            self._quarantine_group(ordered, srv.stats.model,
-                                   "nan_logits")
-            return kl + penalty
-        for i, lane in enumerate(lanes):
-            self._replay_megastep(lane, emits, dones, kl, i)
-            lane.logits = next_logits[i]
-        if self.tracer is not None:
-            # one span per (row, lane) per megastep launch; lane
-            # streams chain launch-to-launch, parented on the row
-            # lifecycle (probe lanes) or the member launch (members)
-            for it, lane in zip(ordered, lanes):
-                probe = lane.tag < 100
-                self.tracer.span(
-                    "probe_decode" if probe else "member_decode",
-                    self._trace_id(it[1]), self.now,
-                    key=("p", lane.tag) if probe
-                    else ("m", lane.tag - 100),
-                    member=None if probe else lane.tag - 100,
-                    model=srv.stats.model, ticks=kl,
-                    steps=lane.steps, done=int(lane.done))
-            d = time.perf_counter() - t0
-            self.metrics.observe(
-                "acar_span_duration", d,
-                phase="probe_decode" if lanes[0].tag < 100
-                else "ensemble_decode",
-                help="host wall seconds per traced lifecycle phase")
-            self.metrics.observe(
-                "acar_decode_launch_seconds", d,
-                server=srv.stats.model,
-                help="wall seconds per megastep decode launch")
-        if self.journal is not None:
-            self.journal.emit(self.now, srv.stats.model, [
-                [it[1].admission, lane.tag, lane.steps,
-                 int(lane.done), lane.tokens[tok0[i]:]]
-                for i, (it, lane) in enumerate(zip(ordered, lanes))])
-        self.metrics.set_gauge(
-            "acar_step_bucket_occupancy", k / bucket,
-            server=srv.stats.model, bucket=str(bucket),
-            help="live-lane fill of the last step-decode bucket")
+        with span("acar.decode.replay", **f):
+            if (self.injector is not None
+                    and all(l.tag >= 100 for l in lanes)
+                    and not np.isfinite(np.asarray(
+                        next_logits[:k], np.float32)).all()):
+                # genuine non-finite member logits: discard the launch
+                # (lane state is untouched) and quarantine — only
+                # checked while an injector is attached, so the
+                # fault-free path never pays the extra device sync
+                self._quarantine_group(ordered, srv.stats.model,
+                                       "nan_logits")
+                return kl + penalty
+            for i, lane in enumerate(lanes):
+                self._replay_megastep(lane, emits, dones, kl, i)
+                lane.logits = next_logits[i]
+            if self.tracer is not None:
+                # one span per (row, lane) per megastep launch; lane
+                # streams chain launch-to-launch, parented on the row
+                # lifecycle (probe lanes) or the member launch
+                for it, lane in zip(ordered, lanes):
+                    probe = lane.tag < 100
+                    self.tracer.span(
+                        "probe_decode" if probe else "member_decode",
+                        self._trace_id(it[1]), self.now,
+                        key=("p", lane.tag) if probe
+                        else ("m", lane.tag - 100),
+                        member=None if probe else lane.tag - 100,
+                        model=srv.stats.model, ticks=kl,
+                        steps=lane.steps, done=int(lane.done))
+            if self.journal is not None:
+                self.journal.emit(self.now, srv.stats.model, [
+                    [it[1].admission, lane.tag, lane.steps,
+                     int(lane.done), lane.tokens[tok0[i]:]]
+                    for i, (it, lane) in enumerate(zip(ordered,
+                                                       lanes))])
+            self.metrics.set_gauge(
+                "acar_step_bucket_occupancy", k / bucket,
+                server=srv.stats.model, bucket=str(bucket),
+                help="live-lane fill of the last step-decode bucket")
         return kl + penalty
 
     # -- phase transitions ---------------------------------------------
@@ -1138,55 +1158,51 @@ class StepLoopRunner:
     def _route(self, rows: List[_Row]) -> None:
         import jax.numpy as jnp
         from repro.serving.engine import intern_answers
-        t0 = time.perf_counter() if self.tracer is not None else 0.0
-        n = self.n
-        self._routed_this_tick += len(rows)
-        # batched route-time extract: decode + extract every row
-        # routing this tick in one call (duplicate probe texts are
-        # extracted once) — element-wise identical to the old per-row
-        # extract loop, so sigma/modes/answers cannot move
-        texts_all: List[str] = []
-        kinds_all: List[str] = []
-        for row in rows:
-            texts = [tok.decode(l.harvest(self.max_new, tok.PAD))
-                     for l in row.lanes]
-            row.probe_texts = texts
-            texts_all.extend(texts)
-            kinds_all.extend([row.request.task.kind] * len(texts))
-        answers_all = extract_batch(texts_all, kinds_all)
-        off = 0
-        for row in rows:
-            row.probe_answers = answers_all[off:off + len(row.lanes)]
-            off += len(row.lanes)
-            srv = self._probe_server(row)
-            srv.pool.release(row.sample_tails.reshape(-1))
-            row.sample_tails = None
-            row.lanes = []
-            srv._sample_usage()
-        # per-row interning namespaces: sigma/majority/judge are
-        # within-row functions, invariant to interning order
-        ids = np.stack([intern_answers(row.probe_answers)
-                        for row in rows]).reshape(len(rows), n)
-        sig = sigma_batch(jnp.asarray(ids))
-        modes = np.asarray(self.eng.route_modes(
-            sig, [r.admission for r in rows]))
-        for i, row in enumerate(rows):
-            row.sigma = float(np.asarray(sig)[i])
-            row.mode = int(modes[i])
-            if self._quarantined:
-                self._apply_degraded_mode(row)
-            if self.tracer is not None:
-                self.tracer.span("route", self._trace_id(row),
-                                 self.now, sigma=row.sigma,
-                                 mode=row.mode,
-                                 n_samples=len(row.probe_answers))
-            row.member_answers = [None] * len(self.eng.ensemble)
-            self._spawn_members(row)
-        if self.tracer is not None:
-            self.metrics.observe(
-                "acar_span_duration", time.perf_counter() - t0,
-                phase="route",
-                help="host wall seconds per traced lifecycle phase")
+        with self.spans.span("acar.route", [r.admission for r in rows]):
+            n = self.n
+            self._routed_this_tick += len(rows)
+            # batched route-time extract: decode + extract every row
+            # routing this tick in one call (duplicate probe texts are
+            # extracted once) — element-wise identical to the old per-row
+            # extract loop, so sigma/modes/answers cannot move
+            texts_all: List[str] = []
+            kinds_all: List[str] = []
+            for row in rows:
+                texts = [tok.decode(l.harvest(self.max_new, tok.PAD))
+                         for l in row.lanes]
+                row.probe_texts = texts
+                texts_all.extend(texts)
+                kinds_all.extend([row.request.task.kind] * len(texts))
+            answers_all = extract_batch(texts_all, kinds_all)
+            off = 0
+            for row in rows:
+                row.probe_answers = answers_all[off:off + len(row.lanes)]
+                off += len(row.lanes)
+                srv = self._probe_server(row)
+                srv.pool.release(row.sample_tails.reshape(-1))
+                row.sample_tails = None
+                row.lanes = []
+                srv._sample_usage()
+            # per-row interning namespaces: sigma/majority/judge are
+            # within-row functions, invariant to interning order
+            ids = np.stack([intern_answers(row.probe_answers)
+                            for row in rows]).reshape(len(rows), n)
+            sig = sigma_batch(jnp.asarray(ids))
+            modes = np.asarray(self.eng.route_modes(
+                sig, [r.admission for r in rows]))
+            for i, row in enumerate(rows):
+                row.sigma = float(np.asarray(sig)[i])
+                row.mode = int(modes[i])
+                self._stamp(row, 1)
+                if self._quarantined:
+                    self._apply_degraded_mode(row)
+                if self.tracer is not None:
+                    self.tracer.span("route", self._trace_id(row),
+                                     self.now, sigma=row.sigma,
+                                     mode=row.mode,
+                                     n_samples=len(row.probe_answers))
+                row.member_answers = [None] * len(self.eng.ensemble)
+                self._spawn_members(row)
 
     def _member_needed(self, mode: int, mi: int) -> bool:
         if mi in self._quarantined:
@@ -1338,63 +1354,61 @@ class StepLoopRunner:
     def _judge(self, row: _Row) -> None:
         import jax.numpy as jnp
         from repro.serving.engine import intern_answers, judge_batch
-        t0 = time.perf_counter() if self.tracer is not None else 0.0
-        table: Dict[str, int] = {}
-        probe_ids = intern_answers(row.probe_answers,
-                                   table).reshape(1, self.n)
-        col = np.full(len(self.eng.ensemble), -1, np.int32)
-        for mi, a in enumerate(row.member_answers):
-            if a is not None:
-                col[mi] = table.setdefault(a, len(table))
-        final = judge_batch(
-            jnp.asarray(col[None]),
-            majority_vote_batch(jnp.asarray(probe_ids)),
-            jnp.asarray([row.mode], np.int32))
-        rev = {v: k for k, v in table.items()}
-        row.final_answer = rev[int(np.asarray(final)[0])]
-        if self.tracer is not None:
-            self.tracer.span(
-                "judge", self._trace_id(row), self.now, mode=row.mode,
-                members=[mi for mi, a
-                         in enumerate(row.member_answers or [])
-                         if a is not None])
-            self.metrics.observe(
-                "acar_span_duration", time.perf_counter() - t0,
-                phase="judge",
-                help="host wall seconds per traced lifecycle phase")
+        with self.spans.span("acar.judge", (row.admission,)):
+            table: Dict[str, int] = {}
+            probe_ids = intern_answers(row.probe_answers,
+                                       table).reshape(1, self.n)
+            col = np.full(len(self.eng.ensemble), -1, np.int32)
+            for mi, a in enumerate(row.member_answers):
+                if a is not None:
+                    col[mi] = table.setdefault(a, len(table))
+            final = judge_batch(
+                jnp.asarray(col[None]),
+                majority_vote_batch(jnp.asarray(probe_ids)),
+                jnp.asarray([row.mode], np.int32))
+            rev = {v: k for k, v in table.items()}
+            row.final_answer = rev[int(np.asarray(final)[0])]
+            if self.tracer is not None:
+                self.tracer.span(
+                    "judge", self._trace_id(row), self.now, mode=row.mode,
+                    members=[mi for mi, a
+                             in enumerate(row.member_answers or [])
+                             if a is not None])
 
     def _retire(self, row: _Row) -> None:
-        self._unreserve(row, row.reserved)
-        row.phase = "done"
-        row.retired_at = self.now
-        arr, adm, _ = self.stats.timeline[row.admission]
-        self.stats.timeline[row.admission] = (arr, adm, self.now)
-        self.stats.retired += 1
-        self.done_rows[row.admission] = row
-        if self.journal is not None:
-            self.journal.retire({
-                "adm": row.admission,
-                "task_id": row.request.task.task_id,
-                "sigma": row.sigma, "mode": row.mode,
-                "probe_texts": row.probe_texts,
-                "probe_answers": row.probe_answers,
-                "member_answers": row.member_answers,
-                "final_answer": row.final_answer,
-                "aborted": row.aborted,
-                "timeline": list(self.stats.timeline[row.admission]),
-            }, self.now)
-        if self.tracer is not None:
-            self.tracer.span("retire", self._trace_id(row), self.now,
-                             task_id=row.request.task.task_id,
-                             final_answer=row.final_answer,
-                             sigma=row.sigma, mode=row.mode,
-                             aborted=row.aborted)
-            if (getattr(self.tracer, "attribution", False)
-                    and row.mode >= 2 and row.aborted is None
-                    and row.member_answers is not None):
-                # full-arena row: schedule on-capacity leave-one-out
-                # recomputation (drained on idle ticks; see run())
-                self._attrib_queue.append(row)
+        with self.spans.span("acar.retire", (row.admission,)):
+            self._unreserve(row, row.reserved)
+            row.phase = "done"
+            row.retired_at = self.now
+            self._stamp(row, 2)
+            arr, adm, _ = self.stats.timeline[row.admission]
+            self.stats.timeline[row.admission] = (arr, adm, self.now)
+            self.stats.retired += 1
+            self.done_rows[row.admission] = row
+            if self.journal is not None:
+                self.journal.retire({
+                    "adm": row.admission,
+                    "task_id": row.request.task.task_id,
+                    "sigma": row.sigma, "mode": row.mode,
+                    "probe_texts": row.probe_texts,
+                    "probe_answers": row.probe_answers,
+                    "member_answers": row.member_answers,
+                    "final_answer": row.final_answer,
+                    "aborted": row.aborted,
+                    "timeline": list(self.stats.timeline[row.admission]),
+                }, self.now)
+            if self.tracer is not None:
+                self.tracer.span("retire", self._trace_id(row), self.now,
+                                 task_id=row.request.task.task_id,
+                                 final_answer=row.final_answer,
+                                 sigma=row.sigma, mode=row.mode,
+                                 aborted=row.aborted)
+                if (getattr(self.tracer, "attribution", False)
+                        and row.mode >= 2 and row.aborted is None
+                        and row.member_answers is not None):
+                    # full-arena row: schedule on-capacity leave-one-out
+                    # recomputation (drained on idle ticks; see run())
+                    self._attrib_queue.append(row)
 
     # -- on-capacity counterfactual attribution ------------------------
     def _attribute_row(self, row: _Row) -> None:
@@ -1446,60 +1460,68 @@ class StepLoopRunner:
                 help="rows per lifecycle phase at the last step "
                      "(route_pending: resolved within this step)")
 
+    def _tick(self) -> None:
+        """One iteration of the loop: admit, launch this tick's prefill
+        and decode groups, promote, advance the virtual clock."""
+        with self.spans.span("acar.admit"):
+            self._admit_ready()
+        if self.injector is not None:
+            self._fault_tick()
+        per_server: Dict[object, int] = {}
+        self._tick_extra = {}
+        self._routed_this_tick = 0
+        for key, items in sorted(self._prefill_groups().items(),
+                                 key=lambda kv: kv[0][1:]):
+            # a chunked launch charges one tick; a one-shot launch
+            # (quant/ring/lanes) charges its dense-equivalent
+            # chunk count, so layout choice never skews latency
+            cost = self._run_prefill_group(key, items)
+            per_server[key[0]] = per_server.get(key[0], 0) + cost
+        for key, items in sorted(self._decode_groups().items(),
+                                 key=lambda kv: (kv[0][1],
+                                                 kv[0][2])):
+            # a megastep launch charges its fused tick count: the
+            # virtual clock measures device occupancy, not launch
+            # overhead (that is megastep_bench's wall-clock gate)
+            kl = self._run_decode_group(key, items)
+            per_server[key[0]] = per_server.get(key[0], 0) + kl
+        with self.spans.span("acar.promote"):
+            self._promote()
+        # dense-fallback members ran whole generations on their
+        # own executors during promotion
+        for key, cost in self._tick_extra.items():
+            per_server[key] = per_server.get(key, 0) + cost
+        self.active = [r for r in self.active if r.phase != "done"]
+        self._emit_phase_gauges()
+        # servers are independent executors: the tick takes as
+        # long as its busiest server; same-server programs
+        # serialize. Idle ticks launch nothing (invocations stay
+        # honest) but time still passes.
+        tick_cost = max(per_server.values(), default=0)
+        if self.tracer is not None and self._attrib_queue:
+            # attribution is pure host recompute over retired
+            # rows: spend idle device ticks on it, never busy ones
+            self._drain_attribution(self.planner.attribution_quota(
+                tick_cost, len(self._attrib_queue)))
+        self.stats.ticks += 1
+        self.stats.invocations += sum(per_server.values())
+        self.now += max(1, tick_cost)
+        if tick_cost == 0 and not self.active and len(self.queue):
+            # idle: jump the virtual clock to the next admission
+            # event (a future arrival, or the oldest request's
+            # fill-or-timeout instant)
+            head = self.queue.peek()
+            if head.arrival_time > self.now:
+                self.now = head.arrival_time
+            elif not self.queue.ready(self.now):
+                nxt = self.queue.next_ready_at()
+                if nxt is not None:
+                    self.now = max(self.now, nxt)
+
     def run(self) -> StepStats:
         while len(self.queue) or self.active or self._displaced:
-            self._admit_ready()
-            if self.injector is not None:
-                self._fault_tick()
-            per_server: Dict[object, int] = {}
-            self._tick_extra = {}
-            self._routed_this_tick = 0
-            for key, items in sorted(self._prefill_groups().items(),
-                                     key=lambda kv: kv[0][1:]):
-                # a chunked launch charges one tick; a one-shot launch
-                # (quant/ring/lanes) charges its dense-equivalent
-                # chunk count, so layout choice never skews latency
-                cost = self._run_prefill_group(key, items)
-                per_server[key[0]] = per_server.get(key[0], 0) + cost
-            for key, items in sorted(self._decode_groups().items(),
-                                     key=lambda kv: (kv[0][1],
-                                                     kv[0][2])):
-                # a megastep launch charges its fused tick count: the
-                # virtual clock measures device occupancy, not launch
-                # overhead (that is megastep_bench's wall-clock gate)
-                kl = self._run_decode_group(key, items)
-                per_server[key[0]] = per_server.get(key[0], 0) + kl
-            self._promote()
-            # dense-fallback members ran whole generations on their
-            # own executors during promotion
-            for key, cost in self._tick_extra.items():
-                per_server[key] = per_server.get(key, 0) + cost
-            self.active = [r for r in self.active if r.phase != "done"]
-            self._emit_phase_gauges()
-            # servers are independent executors: the tick takes as
-            # long as its busiest server; same-server programs
-            # serialize. Idle ticks launch nothing (invocations stay
-            # honest) but time still passes.
-            tick_cost = max(per_server.values(), default=0)
-            if self.tracer is not None and self._attrib_queue:
-                # attribution is pure host recompute over retired
-                # rows: spend idle device ticks on it, never busy ones
-                self._drain_attribution(self.planner.attribution_quota(
-                    tick_cost, len(self._attrib_queue)))
-            self.stats.ticks += 1
-            self.stats.invocations += sum(per_server.values())
-            self.now += max(1, tick_cost)
-            if tick_cost == 0 and not self.active and len(self.queue):
-                # idle: jump the virtual clock to the next admission
-                # event (a future arrival, or the oldest request's
-                # fill-or-timeout instant)
-                head = self.queue.peek()
-                if head.arrival_time > self.now:
-                    self.now = head.arrival_time
-                elif not self.queue.ready(self.now):
-                    nxt = self.queue.next_ready_at()
-                    if nxt is not None:
-                        self.now = max(self.now, nxt)
+            with self.spans.span("acar.tick"):
+                self._tick()
         if self.tracer is not None and self._attrib_queue:
             # the run drained before the queue did: flush the rest so
             # every escalated row gets its counterfactual events
@@ -1574,12 +1596,13 @@ class ShardedStepLoopRunner(StepLoopRunner):
                  faults: Optional[FaultInjector] = None,
                  journal=None,
                  recovered: Optional[Dict[int, dict]] = None,
-                 tracer=None):
+                 tracer=None, spans: Optional[HostSpans] = None):
         self.smesh = smesh
         self._lost: set = set()            # shards marked lost
         super().__init__(engine, queue, planner, metrics,
                          faults=faults, journal=journal,
-                         recovered=recovered, tracer=tracer)
+                         recovered=recovered, tracer=tracer,
+                         spans=spans)
 
     # -- server topology -----------------------------------------------
     def _init_servers(self) -> None:
@@ -1747,6 +1770,7 @@ class ShardedStepLoopRunner(StepLoopRunner):
                                shard=min(self._lost))
                     self.stats.timeline[row.admission] = (
                         req.arrival_time, self.now, -1)
+                    self._stamp(row, 0)
                     self._abort_row(row, "capacity_rebuild_blocked")
                     continue
                 raise
@@ -1765,6 +1789,7 @@ class ShardedStepLoopRunner(StepLoopRunner):
             self._shard_active[shard] += 1
             self.stats.timeline[row.admission] = (
                 req.arrival_time, self.now, -1)
+            self._stamp(row, 0)
             if self.tracer is not None:
                 self.tracer.span("admit", self._trace_id(row),
                                  self.now, prompt_tokens=s,
@@ -1893,7 +1918,6 @@ class ShardedStepLoopRunner(StepLoopRunner):
         _, c, s = key
         if c < 0:
             return self._run_one_shot_prefill_group(key, items)
-        t0 = time.perf_counter() if self.tracer is not None else 0.0
         parent = items[0][0].parent
         nsh = parent.n_shards
         nbp = pages_for(s, self.page_size)
@@ -1904,69 +1928,68 @@ class ShardedStepLoopRunner(StepLoopRunner):
             per[k].sort(key=lambda it: it[1].admission)
         bucket = self.planner.decode_bucket(
             max(len(p) for p in per))
-        tokens = np.zeros((nsh, bucket, c), np.int32)
-        tables = np.empty((nsh, bucket, nbp), np.int32)
-        starts = np.zeros((nsh, bucket), np.int32)
-        for k in range(nsh):
-            scratch = parent.shards[k]._scratch[:nbp]
-            for i in range(bucket):
-                if i < len(per[k]):
-                    _, row, mx = per[k][i]
+        with self.spans.span("acar.prefill",
+                             [it[1].admission for p in per for it in p],
+                             model=parent.model_name, bucket=bucket,
+                             lanes=len(items)):
+            tokens = np.zeros((nsh, bucket, c), np.int32)
+            tables = np.empty((nsh, bucket, nbp), np.int32)
+            starts = np.zeros((nsh, bucket), np.int32)
+            for k in range(nsh):
+                scratch = parent.shards[k]._scratch[:nbp]
+                for i in range(bucket):
+                    if i < len(per[k]):
+                        _, row, mx = per[k][i]
+                        target = mx if mx is not None else row
+                        starts[k, i] = target.prefill_pos
+                        tokens[k, i] = row.ids[
+                            starts[k, i]:starts[k, i] + c]
+                        tables[k, i, :target.shared.size] = target.shared
+                        if target.tail is not None:
+                            tables[k, i, -1] = target.tail
+                    else:
+                        # pad rows prefill zeros into scratch pages
+                        tables[k, i] = scratch
+            zm = self._model_by_group[id(parent)]
+            prm = self._params_repl[id(parent)]
+            lg, parent.pages = S.prefill_chunk_paged_sharded(
+                zm.cfg, prm, tokens, parent.pages,
+                tables, starts, prompt_len=s, mesh=self.smesh.mesh)
+            for sv in parent.shards:
+                sv.stats.prefill_tokens_computed += bucket * c
+                sv.stats.prefill_chunks += 1
+            self.stats.prefill_chunks += 1
+            self.metrics.inc("acar_prefill_chunks_total",
+                             model=parent.model_name,
+                             help="chunked-prefill device programs run")
+            self.stats.launches += 1
+            # native-dtype, device-resident chunk-final logits (see the
+            # single-device runner), sliced shard-locally — never through
+            # the global array, which would gather cross-device
+            lg_local = _shard_rows(lg)
+            for k in range(nsh):
+                for i, (srv, row, mx) in enumerate(per[k]):
                     target = mx if mx is not None else row
-                    starts[k, i] = target.prefill_pos
-                    tokens[k, i] = row.ids[
-                        starts[k, i]:starts[k, i] + c]
-                    tables[k, i, :target.shared.size] = target.shared
-                    if target.tail is not None:
-                        tables[k, i, -1] = target.tail
-                else:
-                    # pad rows prefill zeros into scratch pages
-                    tables[k, i] = scratch
-        zm = self._model_by_group[id(parent)]
-        prm = self._params_repl[id(parent)]
-        lg, parent.pages = S.prefill_chunk_paged_sharded(
-            zm.cfg, prm, tokens, parent.pages,
-            tables, starts, prompt_len=s, mesh=self.smesh.mesh)
-        for sv in parent.shards:
-            sv.stats.prefill_tokens_computed += bucket * c
-            sv.stats.prefill_chunks += 1
-        self.stats.prefill_chunks += 1
-        self.metrics.inc("acar_prefill_chunks_total",
-                         model=parent.model_name,
-                         help="chunked-prefill device programs run")
-        self.stats.launches += 1
-        # native-dtype, device-resident chunk-final logits (see the
-        # single-device runner), sliced shard-locally — never through
-        # the global array, which would gather cross-device
-        lg_local = _shard_rows(lg)
-        for k in range(nsh):
-            for i, (srv, row, mx) in enumerate(per[k]):
-                target = mx if mx is not None else row
-                target.prefill_pos = int(starts[k, i]) + c
-                sid = None
-                if self.tracer is not None:
-                    sid = self.tracer.span(
-                        "prefill_chunk", self._trace_id(row),
-                        self.now,
-                        key=None if mx is None else ("m", mx.member),
-                        model=parent.model_name,
-                        start=int(starts[k, i]), tokens=c)
-                if target.prefill_pos == s:
-                    target.logits0 = lg_local[k][0, i]
-                    srv._prefix_insert(row.ids.tobytes(),
-                                       target.shared, target.tail,
-                                       target.logits0, tokens=s)
-                    if sid is not None:
-                        self.tracer.kv_insert(
-                            parent.model_name,
-                            hashlib.sha256(
-                                row.ids.tobytes()).hexdigest(),
-                            self._trace_id(row), sid)
-        if self.tracer is not None:
-            self.metrics.observe(
-                "acar_span_duration", time.perf_counter() - t0,
-                phase="prefill",
-                help="host wall seconds per traced lifecycle phase")
+                    target.prefill_pos = int(starts[k, i]) + c
+                    sid = None
+                    if self.tracer is not None:
+                        sid = self.tracer.span(
+                            "prefill_chunk", self._trace_id(row),
+                            self.now,
+                            key=None if mx is None else ("m", mx.member),
+                            model=parent.model_name,
+                            start=int(starts[k, i]), tokens=c)
+                    if target.prefill_pos == s:
+                        target.logits0 = lg_local[k][0, i]
+                        srv._prefix_insert(row.ids.tobytes(),
+                                           target.shared, target.tail,
+                                           target.logits0, tokens=s)
+                        if sid is not None:
+                            self.tracer.kv_insert(
+                                parent.model_name,
+                                hashlib.sha256(
+                                    row.ids.tobytes()).hexdigest(),
+                                self._trace_id(row), sid)
         return 1
 
     def _run_one_shot_prefill_group(self, key, items) -> int:
@@ -1975,7 +1998,6 @@ class ShardedStepLoopRunner(StepLoopRunner):
         runner, and dense always chunks)."""
         import jax.numpy as jnp
         _, _, s = key
-        t0 = time.perf_counter() if self.tracer is not None else 0.0
         parent = items[0][0].parent
         nsh = parent.n_shards
         g = self._geometry(items[0][0], s)
@@ -1986,64 +2008,62 @@ class ShardedStepLoopRunner(StepLoopRunner):
             per[k].sort(key=lambda it: it[1].admission)
         bucket = self.planner.decode_bucket(
             max(len(p) for p in per))
-        tokens = np.zeros((nsh, bucket, s), np.int32)
-        tables = np.empty((nsh, bucket, g.nbp), np.int32)
-        for k in range(nsh):
-            scratch = parent.shards[k]._scratch[:g.nbp]
-            for i in range(bucket):
-                if i < len(per[k]):
-                    _, row, mx = per[k][i]
+        with self.spans.span("acar.prefill",
+                             [it[1].admission for p in per for it in p],
+                             model=parent.model_name, bucket=bucket,
+                             lanes=len(items)):
+            tokens = np.zeros((nsh, bucket, s), np.int32)
+            tables = np.empty((nsh, bucket, g.nbp), np.int32)
+            for k in range(nsh):
+                scratch = parent.shards[k]._scratch[:g.nbp]
+                for i in range(bucket):
+                    if i < len(per[k]):
+                        _, row, mx = per[k][i]
+                        target = mx if mx is not None else row
+                        tokens[k, i] = row.ids
+                        tables[k, i, :target.shared.size] = target.shared
+                        if target.tail is not None:
+                            tables[k, i, -1] = target.tail
+                    else:
+                        # pad rows prefill zeros into scratch pages
+                        tables[k, i] = scratch
+            zm = self._model_by_group[id(parent)]
+            prm = self._params_repl[id(parent)]
+            lg, parent.pages = S.prefill_paged_sharded(
+                zm.cfg, prm, jnp.asarray(tokens), parent.pages,
+                jnp.asarray(tables), mesh=self.smesh.mesh)
+            for sv in parent.shards:
+                sv.stats.prefill_tokens_computed += bucket * s
+            self.metrics.inc("acar_prefill_oneshot_total",
+                             model=parent.model_name,
+                             help="one-shot prefill device programs run "
+                                  "for non-chunkable page layouts")
+            self.stats.launches += 1
+            lg_local = _shard_rows(lg)
+            for k in range(nsh):
+                for i, (srv, row, mx) in enumerate(per[k]):
                     target = mx if mx is not None else row
-                    tokens[k, i] = row.ids
-                    tables[k, i, :target.shared.size] = target.shared
-                    if target.tail is not None:
-                        tables[k, i, -1] = target.tail
-                else:
-                    # pad rows prefill zeros into scratch pages
-                    tables[k, i] = scratch
-        zm = self._model_by_group[id(parent)]
-        prm = self._params_repl[id(parent)]
-        lg, parent.pages = S.prefill_paged_sharded(
-            zm.cfg, prm, jnp.asarray(tokens), parent.pages,
-            jnp.asarray(tables), mesh=self.smesh.mesh)
-        for sv in parent.shards:
-            sv.stats.prefill_tokens_computed += bucket * s
-        self.metrics.inc("acar_prefill_oneshot_total",
-                         model=parent.model_name,
-                         help="one-shot prefill device programs run "
-                              "for non-chunkable page layouts")
-        self.stats.launches += 1
-        lg_local = _shard_rows(lg)
-        for k in range(nsh):
-            for i, (srv, row, mx) in enumerate(per[k]):
-                target = mx if mx is not None else row
-                target.prefill_pos = s
-                target.logits0 = lg_local[k][0, i]
-                srv._prefix_insert(row.ids.tobytes(), target.shared,
-                                   target.tail, target.logits0,
-                                   tokens=s)
-                if self.tracer is not None:
-                    sid = self.tracer.span(
-                        "prefill_chunk", self._trace_id(row),
-                        self.now,
-                        key=None if mx is None else ("m", mx.member),
-                        model=parent.model_name, start=0, tokens=s,
-                        oneshot=1)
-                    self.tracer.kv_insert(
-                        parent.model_name,
-                        hashlib.sha256(row.ids.tobytes()).hexdigest(),
-                        self._trace_id(row), sid)
-        if self.tracer is not None:
-            self.metrics.observe(
-                "acar_span_duration", time.perf_counter() - t0,
-                phase="prefill",
-                help="host wall seconds per traced lifecycle phase")
+                    target.prefill_pos = s
+                    target.logits0 = lg_local[k][0, i]
+                    srv._prefix_insert(row.ids.tobytes(), target.shared,
+                                       target.tail, target.logits0,
+                                       tokens=s)
+                    if self.tracer is not None:
+                        sid = self.tracer.span(
+                            "prefill_chunk", self._trace_id(row),
+                            self.now,
+                            key=None if mx is None else ("m", mx.member),
+                            model=parent.model_name, start=0, tokens=s,
+                            oneshot=1)
+                        self.tracer.kv_insert(
+                            parent.model_name,
+                            hashlib.sha256(row.ids.tobytes()).hexdigest(),
+                            self._trace_id(row), sid)
         return self.planner.chunk_count(s)
 
     def _run_decode_group(self, key, items) -> int:
         import jax.numpy as jnp
         _, temperature, cache_len = key
-        t0 = time.perf_counter() if self.tracer is not None else 0.0
         parent = items[0][0].parent
         nsh = parent.n_shards
         nb = items[0][0].table_width(cache_len - self.max_new,
@@ -2066,118 +2086,121 @@ class ShardedStepLoopRunner(StepLoopRunner):
         # the same shard_map'd megastep, so K must be uniform — take
         # it over all lanes across shards
         kl = self._megastep_span([lane for _, _, lane in items])
-        tables = np.empty((nsh, bucket, nb), np.int32)
-        pos = np.full((nsh, bucket), cache_len - self.max_new,
-                      np.int32)
-        keys = np.zeros((nsh, bucket, 2), np.uint32)
-        steps = np.zeros((nsh, bucket), np.int32)
-        done = np.ones((nsh, bucket), bool)
-        filler = items[0][2].logits    # pad rows sample masked pads
-        live_total = 0
-        # assemble the logits operand shard-locally: each device
-        # stacks its own lanes' rows (device_put is a no-op for a row
-        # already resident; prefix-cache hits seeded on another shard
-        # transfer point-to-point), and the pieces form the
-        # P("data")-sharded global array the launch expects — no
-        # cross-device gathers, no collective per lane. On a 2-D mesh
-        # the spec is still P("data") — logits replicate over "model"
-        # — so every model column of a data row needs its own
-        # single-device copy of that row's piece (a point-to-point
-        # broadcast, still no collective and no host round-trip).
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec
-        mesh_devs = self.smesh.mesh.devices
-        if mesh_devs.ndim == 1:
-            mesh_devs = mesh_devs.reshape(-1, 1)
-        nm = mesh_devs.shape[1]
-        pieces = []
-        for k in range(nsh):
-            scratch = parent.shards[k]._scratch[:nb]
-            rows_k = []
-            for i in range(bucket):
-                if i < len(per[k]):
-                    row, lane = per[k][i]
-                    rows_k.append(
-                        jax.device_put(lane.logits, mesh_devs[k, 0]))
-                    tables[k, i] = lane.block_table
-                    pos[k, i] = cache_len - self.max_new + lane.steps
-                    keys[k, i] = lane.row_key
-                    steps[k, i] = lane.steps
-                    # a lane dropped by a quarantine or shard loss
-                    # earlier this tick decodes masked
-                    done[k, i] = lane.done
-                    live_total += 1
-                else:
-                    rows_k.append(
-                        jax.device_put(filler, mesh_devs[k, 0]))
-                    tables[k, i] = scratch
-            piece = jnp.stack(rows_k)[None]
-            pieces.append(piece)
-            for j in range(1, nm):
-                pieces.append(jax.device_put(piece, mesh_devs[k, j]))
-        logits = jax.make_array_from_single_device_arrays(
-            (nsh, bucket, int(filler.shape[-1])),
-            NamedSharding(self.smesh.mesh, PartitionSpec("data")),
-            pieces)
-        zm = self._model_by_group[id(parent)]
-        prm = self._params_repl[id(parent)]
-        (emits, dones, next_logits,
-         parent.pages) = S.decode_megastep_rows_sharded(
-            zm.cfg, prm, logits, parent.pages,
-            jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(keys),
-            jnp.asarray(steps), jnp.asarray(done), n_ticks=kl,
-            cache_len=cache_len, temperature=temperature,
-            eos_id=tok.EOS, pad_id=tok.PAD, mesh=self.smesh.mesh)
-        emits = np.asarray(emits)      # (nsh, K, bucket)
-        dones = np.asarray(dones)
+        span = self.spans.span
+        f = dict(model=parent.model_name, bucket=bucket,
+                 lanes=len(items), ticks=kl)
+        with span("acar.decode.stage", **f):
+            tables = np.empty((nsh, bucket, nb), np.int32)
+            pos = np.full((nsh, bucket), cache_len - self.max_new,
+                          np.int32)
+            keys = np.zeros((nsh, bucket, 2), np.uint32)
+            steps = np.zeros((nsh, bucket), np.int32)
+            done = np.ones((nsh, bucket), bool)
+            filler = items[0][2].logits  # pad rows sample masked pads
+            live_total = 0
+            # assemble the logits operand shard-locally: each device
+            # stacks its own lanes' rows (device_put is a no-op for a
+            # row already resident; prefix-cache hits seeded on another
+            # shard transfer point-to-point), and the pieces form the
+            # P("data")-sharded global array the launch expects — no
+            # cross-device gathers, no collective per lane. On a 2-D
+            # mesh the spec is still P("data") — logits replicate over
+            # "model" — so every model column of a data row needs its
+            # own single-device copy of that row's piece (a
+            # point-to-point broadcast, still no collective and no
+            # host round-trip).
+            import jax
+            from jax.sharding import NamedSharding, PartitionSpec
+            mesh_devs = self.smesh.mesh.devices
+            if mesh_devs.ndim == 1:
+                mesh_devs = mesh_devs.reshape(-1, 1)
+            nm = mesh_devs.shape[1]
+            pieces = []
+            for k in range(nsh):
+                scratch = parent.shards[k]._scratch[:nb]
+                rows_k = []
+                for i in range(bucket):
+                    if i < len(per[k]):
+                        row, lane = per[k][i]
+                        rows_k.append(jax.device_put(
+                            lane.logits, mesh_devs[k, 0]))
+                        tables[k, i] = lane.block_table
+                        pos[k, i] = cache_len - self.max_new \
+                            + lane.steps
+                        keys[k, i] = lane.row_key
+                        steps[k, i] = lane.steps
+                        # a lane dropped by a quarantine or shard loss
+                        # earlier this tick decodes masked
+                        done[k, i] = lane.done
+                        live_total += 1
+                    else:
+                        rows_k.append(
+                            jax.device_put(filler, mesh_devs[k, 0]))
+                        tables[k, i] = scratch
+                piece = jnp.stack(rows_k)[None]
+                pieces.append(piece)
+                for j in range(1, nm):
+                    pieces.append(jax.device_put(piece, mesh_devs[k, j]))
+            logits = jax.make_array_from_single_device_arrays(
+                (nsh, bucket, int(filler.shape[-1])),
+                NamedSharding(self.smesh.mesh, PartitionSpec("data")),
+                pieces)
+            operands = [jnp.asarray(a)
+                        for a in (tables, pos, keys, steps, done)]
+            zm = self._model_by_group[id(parent)]
+            prm = self._params_repl[id(parent)]
+        with span("acar.decode.launch",
+                  [row.admission for p in per for row, _ in p], **f):
+            (emits, dones, next_logits,
+             parent.pages) = S.decode_megastep_rows_sharded(
+                zm.cfg, prm, logits, parent.pages, *operands,
+                n_ticks=kl, cache_len=cache_len,
+                temperature=temperature, eos_id=tok.EOS,
+                pad_id=tok.PAD, mesh=self.smesh.mesh)
+        with span("acar.decode.wait", **f):
+            emits = np.asarray(emits)      # (nsh, K, bucket)
+            dones = np.asarray(dones)
         self.stats.launches += 1
         self.stats.decode_h2d += 5     # tables, pos, keys, steps, done
         self.stats.decode_d2h += 2     # emits, dones
-        if (self.injector is not None
-                and all(it[2].tag >= 100 for it in items)
-                and not np.isfinite(np.asarray(
-                    next_logits, np.float32)).all()):
-            # genuine non-finite member logits (see StepLoopRunner)
-            self._quarantine_group(items, parent.model_name,
-                                   "nan_logits")
-            return kl + penalty
-        nl_local = _shard_rows(next_logits)
-        for k in range(nsh):
-            for i, (row, lane) in enumerate(per[k]):
-                self._replay_megastep(lane, emits[k], dones[k], kl, i)
-                lane.logits = nl_local[k][0, i]
-        if self.tracer is not None:
+        with span("acar.decode.replay", **f):
+            if (self.injector is not None
+                    and all(it[2].tag >= 100 for it in items)
+                    and not np.isfinite(np.asarray(
+                        next_logits, np.float32)).all()):
+                # genuine non-finite member logits (see StepLoopRunner)
+                self._quarantine_group(items, parent.model_name,
+                                       "nan_logits")
+                return kl + penalty
+            nl_local = _shard_rows(next_logits)
             for k in range(nsh):
-                for row, lane in per[k]:
-                    probe = lane.tag < 100
-                    self.tracer.span(
-                        "probe_decode" if probe else "member_decode",
-                        self._trace_id(row), self.now,
-                        key=("p", lane.tag) if probe
-                        else ("m", lane.tag - 100),
-                        member=None if probe else lane.tag - 100,
-                        model=parent.model_name, ticks=kl,
-                        steps=lane.steps, done=int(lane.done))
-            d = time.perf_counter() - t0
-            self.metrics.observe(
-                "acar_span_duration", d,
-                phase="probe_decode" if items[0][2].tag < 100
-                else "ensemble_decode",
-                help="host wall seconds per traced lifecycle phase")
-            self.metrics.observe(
-                "acar_decode_launch_seconds", d,
-                server=parent.model_name,
-                help="wall seconds per megastep decode launch")
-        if self.journal is not None:
-            self.journal.emit(self.now, parent.model_name, [
-                [row.admission, lane.tag, lane.steps, int(lane.done),
-                 lane.tokens[tok0[id(lane)]:]]
-                for k in range(nsh) for row, lane in per[k]])
-        self.metrics.set_gauge(
-            "acar_step_bucket_occupancy",
-            live_total / (nsh * bucket), server=parent.model_name,
-            bucket=str(bucket),
-            help="live-lane fill of the last step-decode bucket")
+                for i, (row, lane) in enumerate(per[k]):
+                    self._replay_megastep(lane, emits[k], dones[k], kl,
+                                          i)
+                    lane.logits = nl_local[k][0, i]
+            if self.tracer is not None:
+                for k in range(nsh):
+                    for row, lane in per[k]:
+                        probe = lane.tag < 100
+                        self.tracer.span(
+                            "probe_decode" if probe
+                            else "member_decode",
+                            self._trace_id(row), self.now,
+                            key=("p", lane.tag) if probe
+                            else ("m", lane.tag - 100),
+                            member=None if probe else lane.tag - 100,
+                            model=parent.model_name, ticks=kl,
+                            steps=lane.steps, done=int(lane.done))
+            if self.journal is not None:
+                self.journal.emit(self.now, parent.model_name, [
+                    [row.admission, lane.tag, lane.steps,
+                     int(lane.done), lane.tokens[tok0[id(lane)]:]]
+                    for k in range(nsh) for row, lane in per[k]])
+            self.metrics.set_gauge(
+                "acar_step_bucket_occupancy",
+                live_total / (nsh * bucket), server=parent.model_name,
+                bucket=str(bucket),
+                help="live-lane fill of the last step-decode bucket")
         return kl + penalty
 
     # -- observability -------------------------------------------------

@@ -21,12 +21,21 @@ The tracer also carries the KV provenance map: prefix-cache owners are
 recorded at insert (first writer in admission order — deterministic),
 so a later hit can name its donor trace and PROV can materialize the
 reuse as a ``wasDerivedFrom`` edge (``teamllm.prov``).
+
+``HostSpans`` is the other kind: wall-clock spans of the step loop's
+host work (``acar.call``, ``acar.tick``, ``acar.decode.wait``, ...),
+always on, kept in memory for the run and written into the profiler's
+host plane when a profiler records. Nothing in it is hashed or
+journaled, so it times the serving path without changing it.
 """
 from __future__ import annotations
 
 import time
 from pathlib import Path
+from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple, Union
+
+from jax.profiler import TraceAnnotation
 
 from repro.teamllm.spans import SpanLog, span_record
 
@@ -129,3 +138,79 @@ class SpanTracer:
         if self.path is None:
             return self.log.head
         return self.log.flush(self.path)
+
+
+# ----------------------------------------------------------------------
+# wall-clock host spans
+# ----------------------------------------------------------------------
+@dataclass(slots=True)
+class HostSpan:
+    """One host span. ``parent`` indexes the enclosing span in the same
+    log (-1 at the root); ``start``/``end`` are ``time.perf_counter``
+    seconds. ``fields`` (model, bucket, lanes, ticks) also ride the
+    profiler's annotation; ``rows``, the admission indices of the rows
+    a group launch served (the audit trace ids' index), stay here:
+    ``launch/serve.request_walls`` counts a request's decode launches
+    from them."""
+    name: str
+    parent: int
+    start: float
+    end: float = 0.0
+    fields: Dict[str, Any] = field(default_factory=dict)
+    rows: Tuple[int, ...] = ()
+
+
+class HostSpans:
+    """The host spans of one ``run_stepped`` call, in opening order.
+
+    ``span(name, ...)`` opens a ``jax.profiler.TraceAnnotation`` of the
+    same name (a flag check unless a profiler records, when it lands on
+    the profiler's host plane, on the device trace's clock) and appends
+    one ``HostSpan``; spans nest by ``with``. The log lives as long as
+    the run's result and has no file or exporter. Given ``metrics``
+    (the run's ``PromCounters``, handed over only while an audit tracer
+    is armed), every span also observes
+    ``acar_span_duration{phase=<name>}`` on exit, with a ``model``
+    label on the spans that carry one (prefill and decode), so probe
+    and member time stay apart."""
+
+    def __init__(self, metrics=None):
+        self.records: List[HostSpan] = []
+        self.metrics = metrics
+        self._open: List[int] = []
+
+    def span(self, name: str, rows: Tuple[int, ...] = (),
+             **fields: Any) -> "_HostSpanCtx":
+        return _HostSpanCtx(self, name, rows, fields)
+
+
+class _HostSpanCtx:
+    __slots__ = ("log", "name", "rows", "fields", "rec", "ann")
+
+    def __init__(self, log: HostSpans, name: str, rows, fields):
+        self.log, self.name, self.rows, self.fields = \
+            log, name, rows, fields
+
+    def __enter__(self) -> HostSpan:
+        log = self.log
+        self.ann = TraceAnnotation(self.name, **self.fields)
+        self.ann.__enter__()
+        self.rec = HostSpan(self.name,
+                            log._open[-1] if log._open else -1,
+                            time.perf_counter(), 0.0, self.fields,
+                            tuple(self.rows))
+        log._open.append(len(log.records))
+        log.records.append(self.rec)
+        return self.rec
+
+    def __exit__(self, *exc) -> None:
+        rec = self.rec
+        rec.end = time.perf_counter()
+        self.log._open.pop()
+        self.ann.__exit__(*exc)
+        if self.log.metrics is not None:
+            model = rec.fields.get("model")
+            self.log.metrics.observe(
+                "acar_span_duration", rec.end - rec.start,
+                phase=rec.name, **({"model": model} if model else {}),
+                help="host wall seconds per step-loop span")

@@ -241,3 +241,55 @@ def test_step_loop_dense_member_fallback_bit_equals_wave():
     assert res_w.final_answers == res_s.final_answers
     assert res_w.member_answers == res_s.member_answers
     np.testing.assert_array_equal(res_w.modes, res_s.modes)
+
+
+# ----------------------------------------------------------------------
+# per-request wall stamps (StepStats.wall)
+# ----------------------------------------------------------------------
+def test_wall_stamps_order_and_stay_out_of_journal_and_records(
+        tmp_path, monkeypatch):
+    """Every row is stamped admitted <= routed <= retired on the host
+    clock. The stamps reach neither the journal nor a record: a run on
+    a clock shifted by a day writes the same journal bytes and record
+    hashes."""
+    import time
+    from types import SimpleNamespace
+
+    from harness.simulate import paged_zoo
+    from repro.configs.acar import ACARConfig
+    from repro.serving import (
+        BatchedACAREngine, MicroBatchPolicy, step_loop, trace_records,
+        tracing)
+
+    tasks = [mk_task(i, text=f"{i % 10} + 3{i} = ") for i in range(5)]
+    probe, ensemble = paged_zoo(seed=0)
+    policy = MicroBatchPolicy(max_batch_size=4,
+                              max_batch_tokens=1 << 20)
+
+    def serve(name):
+        eng = BatchedACAREngine(ACARConfig(probe_temperature=0.9,
+                                           seed=0), probe, ensemble,
+                                max_new_tokens=4)
+        res = eng.run_stepped(tasks, policy, chunk_tokens=4,
+                              megastep="auto",
+                              journal_path=tmp_path / name)
+        hashes = [r.record_hash() for r in trace_records(
+            "run", tasks, res, [m.name for m in ensemble])]
+        return res, (tmp_path / name).read_bytes(), hashes
+
+    res, journal, hashes = serve("a.jsonl")
+    wall = res.step.wall
+    assert sorted(wall) == list(range(len(tasks)))
+    for admitted, routed, retired in wall.values():
+        assert admitted <= routed <= retired
+
+    shifted = SimpleNamespace(
+        perf_counter=lambda: time.perf_counter() + 86400.0)
+    monkeypatch.setattr(step_loop, "time", shifted)
+    monkeypatch.setattr(tracing, "time", shifted)
+    res_b, journal_b, hashes_b = serve("b.jsonl")
+    assert min(a for a, _, _ in res_b.step.wall.values()) > \
+        max(r for _, _, r in wall.values())
+    assert res_b.step.host_spans[1].start > res.step.host_spans[-1].end
+    assert journal_b == journal
+    assert hashes_b == hashes

@@ -9,7 +9,12 @@ stream parenting (row lifecycle vs forked member/probe streams) and
 first-writer-wins KV provenance, and ``teamllm.prov`` materializes a
 walkable PROV graph whose lineage walk re-verifies span hashes —
 including catching a tampered span.
+
+The last tests serve a tiny fleet through ``run_stepped`` to pin the
+wall-clock host spans (``HostSpans``): their names and nesting, in the
+in-memory log and on the profiler's host plane alike.
 """
+import collections
 import copy
 
 import pytest
@@ -245,3 +250,137 @@ def test_mode0_answer_derives_from_probe():
                and r.get("entity") == "answer:a#0"
                and r.get("source") == "probe:a#0"
                for r in lin["records"])
+
+
+# ----------------------------------------------------------------------
+# wall-clock host spans of the step loop (serving/tracing.HostSpans)
+# ----------------------------------------------------------------------
+STEP_SPANS = {
+    "acar.call", "acar.call.setup", "acar.call.finish", "acar.tick",
+    "acar.admit", "acar.prefill", "acar.decode.stage",
+    "acar.decode.launch", "acar.decode.wait", "acar.decode.replay",
+    "acar.promote", "acar.route", "acar.judge", "acar.retire"}
+
+
+@pytest.fixture(scope="module")
+def serve():
+    """A tiny fleet serving five prompts through ``run_stepped``, once
+    already: the pools have grown and the programs compiled, and the
+    prefix cache is off, so every later call logs alike."""
+    from harness.simulate import paged_zoo
+    from repro.configs.acar import ACARConfig
+    from repro.data.tasks import Task
+    from repro.serving import BatchedACAREngine, MicroBatchPolicy
+
+    probe, ensemble = paged_zoo(seed=0)
+    eng = BatchedACAREngine(ACARConfig(probe_temperature=0.9, seed=0),
+                            probe, ensemble, max_new_tokens=4,
+                            kv_prefix_cache=0)
+    tasks = [Task(task_id=f"h-{i}", benchmark="arithmetic", kind="math",
+                  text=f"{i} + 1{i} = ", gold="2", difficulty=0.0)
+             for i in range(5)]
+    policy = MicroBatchPolicy(max_batch_size=4, max_batch_tokens=1 << 20)
+
+    def call(tracer=None):
+        return eng.run_stepped(tasks, policy, chunk_tokens=4,
+                               megastep="auto", tracer=tracer)
+    call()
+    return call
+
+
+def _structure(log):
+    return [(sp.name, log[sp.parent].name if sp.parent >= 0 else None)
+            for sp in log]
+
+
+def test_host_spans_nest_and_cover_the_step_loop(serve):
+    log = serve().step.host_spans
+    assert {sp.name for sp in log} == STEP_SPANS
+    assert log[0].name == "acar.call" and log[0].parent == -1
+    assert all(0 <= sp.parent < i for i, sp in enumerate(log) if i)
+    for sp in log:
+        assert sp.start <= sp.end
+        if sp.parent >= 0:
+            up = log[sp.parent]
+            assert up.start <= sp.start and sp.end <= up.end
+    parents = dict(_structure(log))
+    assert parents["acar.tick"] == "acar.call"
+    for name in ("acar.admit", "acar.prefill", "acar.decode.stage",
+                 "acar.decode.launch", "acar.decode.wait",
+                 "acar.decode.replay", "acar.promote"):
+        assert parents[name] == "acar.tick"
+    launch = next(sp for sp in log if sp.name == "acar.decode.launch")
+    assert set(launch.fields) == {"model", "bucket", "lanes", "ticks"}
+    assert launch.rows and launch.fields["lanes"] <= launch.fields[
+        "bucket"]
+
+
+def test_host_spans_land_on_the_profiler_host_plane(serve, tmp_path):
+    """Every span is a TraceAnnotation on the profiler's host plane,
+    nested as in the in-memory log, and a run with no profiler attached
+    logs the same structure."""
+    import jax
+    from jax.profiler import ProfileData
+
+    quiet = serve().step.host_spans
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        log = serve().step.host_spans
+    finally:
+        jax.profiler.stop_trace()
+    assert _structure(quiet) == _structure(log)
+
+    planes = ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb")))).planes
+    # by start, an enclosing span before the spans it holds
+    events = sorted(
+        ((ev.start_ns, -ev.duration_ns, ev.name, dict(ev.stats))
+         for plane in planes if plane.name.startswith("/host:")
+         for line in plane.lines for ev in line.events
+         if ev.name.startswith("acar.")), key=lambda e: e[:2])
+    assert [e[2] for e in events] == [sp.name for sp in log]
+    for i, sp in enumerate(log):
+        if sp.parent >= 0:
+            s, neg, _, _ = events[i]
+            ps, pneg, _, _ = events[sp.parent]
+            assert ps <= s and s - neg <= ps - pneg, sp.name
+    launches = [e[3] for e in events if e[2] == "acar.decode.launch"]
+    assert launches and all(
+        {"model", "bucket", "lanes", "ticks"} <= set(st)
+        for st in launches)
+
+
+def test_host_spans_feed_the_histogram_only_while_armed(serve):
+    armed = serve(SpanTracer())
+    quiet = serve()
+    assert _structure(armed.step.host_spans) == \
+        _structure(quiet.step.host_spans)
+    # decode spans carry their model as a label: probe and member
+    # waits are apart, and together count every wait
+    waits = collections.Counter(
+        sp.fields["model"] for sp in armed.step.host_spans
+        if sp.name == "acar.decode.wait")
+    assert len(waits) > 1
+    for model, n in waits.items():
+        assert armed.metrics.get_histogram(
+            "acar_span_duration", phase="acar.decode.wait",
+            model=model)[1] == n
+    assert armed.metrics.get_histogram(
+        "acar_span_duration", phase="acar.promote")[1] == sum(
+        sp.name == "acar.promote" for sp in armed.step.host_spans)
+    assert "acar_span_duration" not in quiet.metrics.render()
+
+
+def test_serve_reads_request_walls_from_stamps_and_launch_rows(serve):
+    """The operator's per-request readout: every request routes no
+    later than it retires, and rides at least one decode launch."""
+    from repro.launch.serve import request_walls
+
+    step = serve().step
+    got = request_walls(step)
+    assert 0 < got["time_to_route_p50_ms"] <= got["request_p50_ms"] \
+        <= got["request_p90_ms"]
+    rode = {i for sp in step.host_spans
+            if sp.name == "acar.decode.launch" for i in sp.rows}
+    assert rode == set(step.wall)
+    assert got["decode_launches_per_request"] >= 1
