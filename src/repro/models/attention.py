@@ -337,26 +337,10 @@ def gqa_decode(cfg: ModelConfig, p: dict, x_t: jax.Array, cache: dict,
     return y, {"k": k_cache, "v": v_cache}
 
 
-def gqa_decode_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
-                     k_pages: jax.Array, v_pages: jax.Array,
-                     block_table: jax.Array, pos: jax.Array, *,
-                     cache_len: int
-                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Single-token GQA decode against a paged KV cache.
-
-    x_t: (B, d); k_pages/v_pages: (P, KV, page_size, Dh) — one layer's
-    slice of the page pool; block_table: (B, NB) int32 page ids;
-    pos: scalar int32, or (B,) int32 per-row positions (step-level
-    serving mixes rows at different depths in one decode batch);
-    cache_len: static dense-equivalent cache length (prompt + max_new).
-
-    Bit-equivalence contract: the gathered page view sliced to
-    ``cache_len`` feeds the *same* ``decode_attention`` with the same
-    shapes and values as the dense path's contiguous cache, so the
-    output is bit-identical. Stale bytes in recycled pages sit at
-    positions > pos and are masked to the same -1e30 the dense path's
-    zero-initialised slots are, before any softmax math runs.
-    """
+def _paged_decode_qkv(cfg: ModelConfig, p: dict, x_t: jax.Array,
+                      pos: jax.Array):
+    """The token's q (B, H, Dh), k and v (B, KV, Dh), roped at
+    ``pos`` (scalar or (B,))."""
     b, _ = x_t.shape
     hd = cfg.resolved_head_dim
     kv = cfg.num_kv_heads
@@ -364,48 +348,116 @@ def gqa_decode_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
         b, cfg.num_heads, hd)
     k = jnp.einsum("bd,dh->bh", x_t, p["wk"]).reshape(b, kv, hd)
     v = jnp.einsum("bd,dh->bh", x_t, p["wv"]).reshape(b, kv, hd)
-    per_row = jnp.ndim(pos) == 1
     if cfg.use_rope:
-        pos_b = pos[:, None] if per_row else jnp.broadcast_to(
-            pos, (1, 1))
+        pos_b = pos[:, None] if jnp.ndim(pos) == 1 else \
+            jnp.broadcast_to(pos, (1, 1))
         q = apply_rope(q[:, None], pos_b, cfg.rope_theta)[:, 0]
         k = apply_rope(k[:, None], pos_b, cfg.rope_theta)[:, 0]
+    return q, k, v
 
-    ps = k_pages.shape[2]
-    if per_row:
+
+def _write_slot(block_table: jax.Array, pos: jax.Array, ps: int):
+    """(page id (B,), slot) each row writes position ``pos`` to."""
+    if jnp.ndim(pos) == 1:
         page_ids = jnp.take_along_axis(
-            block_table, (pos // ps)[:, None], axis=1)[:, 0]  # (B,)
-        slot = pos % ps                                       # (B,)
+            block_table, (pos // ps)[:, None], axis=1)[:, 0]
     else:
-        page_ids = jnp.take(block_table, pos // ps, axis=1)   # (B,)
-        slot = pos % ps
-    k_pages = k_pages.at[page_ids, :, slot].set(
-        k.astype(k_pages.dtype))
-    v_pages = v_pages.at[page_ids, :, slot].set(
-        v.astype(v_pages.dtype))
+        page_ids = jnp.take(block_table, pos // ps, axis=1)
+    return page_ids, pos % ps
 
-    if cfg.use_pallas:
-        # TPU deployment: block-table flash-decode kernel reads the
-        # pages in place (no gathered copy). Off-TPU the op dispatches
-        # to the gather-based oracle.
-        from repro.kernels import ops
-        lengths = jnp.broadcast_to(pos + 1, (b,)).astype(jnp.int32)
-        out = ops.paged_decode_attention(q, k_pages, v_pages,
-                                         block_table, lengths)
-    else:
-        k_cache = gather_pages(k_pages, block_table, cache_len)
-        v_cache = gather_pages(v_pages, block_table, cache_len)
-        out = decode_attention(q, k_cache, v_cache,
-                               jnp.arange(cache_len), pos)
-    out = out.reshape(b, cfg.num_heads * hd)
+
+def _decode_out(cfg: ModelConfig, p: dict, out: jax.Array) -> jax.Array:
+    """Attention output (B, H, Dh) through the output projection."""
+    out = out.reshape(out.shape[0], cfg.num_heads * cfg.resolved_head_dim)
     # under tensor parallelism wq/wk/wv are head-column-sharded (cfg
     # carries the local head counts) and wo is replicated: gather the
     # per-head outputs back to the full head axis before the output
     # projection — attention itself is head-local, so each shard's
     # slice is bit-identical to the same heads on one device
     out = tp_all_gather(out)
-    y = jnp.einsum("bh,hd->bd", out, p["wo"])
-    return y, k_pages, v_pages
+    return jnp.einsum("bh,hd->bd", out, p["wo"])
+
+
+def gqa_decode_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
+                     k_pages: jax.Array, v_pages: jax.Array,
+                     block_table: jax.Array, pos: jax.Array
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Single-token GQA decode against one layer's slice of the paged
+    KV cache through the block-table flash-decode kernel
+    (``cfg.use_pallas``; without it the dense step decodes through
+    ``gqa_decode_paged_pool``).
+
+    x_t: (B, d); k_pages/v_pages: (P, KV, page_size, Dh);
+    block_table: (B, NB) int32 page ids; pos: scalar int32, or (B,)
+    int32 per-row positions (step-level serving mixes rows at
+    different depths in one decode batch). The kernel reads the pages
+    in place (no gathered copy); off the TPU the op dispatches to the
+    gather-based oracle.
+    """
+    from repro.kernels import ops
+    b = x_t.shape[0]
+    q, k, v = _paged_decode_qkv(cfg, p, x_t, pos)
+    page_ids, slot = _write_slot(block_table, pos, k_pages.shape[2])
+    k_pages = k_pages.at[page_ids, :, slot].set(
+        k.astype(k_pages.dtype))
+    v_pages = v_pages.at[page_ids, :, slot].set(
+        v.astype(v_pages.dtype))
+    lengths = jnp.broadcast_to(pos + 1, (b,)).astype(jnp.int32)
+    out = ops.paged_decode_attention(q, k_pages, v_pages, block_table,
+                                     lengths)
+    return _decode_out(cfg, p, out), k_pages, v_pages
+
+
+def _gather_layer_pages(pool: jax.Array, layer: jax.Array,
+                        block_table: jax.Array, length: int) -> jax.Array:
+    """Each row's whole pages of one layer, read by ``[layer,
+    block_table]`` straight from the pool ``(L, P, KV, page_size,
+    Dh)``: ``(B, NB, KV, page_size, Dh)``, made token-major and cut to
+    ``length``, which gives the ``(B, length, KV, Dh)`` view
+    ``gather_pages`` gives from a layer's slice."""
+    b, nb = block_table.shape
+    rows = jnp.swapaxes(pool[layer, block_table], 2, 3)
+    rows = rows.reshape(b, nb * pool.shape[3], *rows.shape[3:])
+    return rows[:, :length]
+
+
+def gqa_decode_paged_pool(cfg: ModelConfig, p: dict, x_t: jax.Array,
+                          k_pool: jax.Array, v_pool: jax.Array,
+                          layer: jax.Array, block_table: jax.Array,
+                          pos: jax.Array, *, cache_len: int
+                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Single-token GQA decode against the whole paged KV cache,
+    updated in place.
+
+    x_t: (B, d); k_pool/v_pool: (L, P, KV, page_size, Dh), carried
+    through the layer scan; layer: int32 scalar; block_table: (B, NB)
+    int32 page ids; pos: scalar int32, or (B,) int32 per-row positions
+    (step-level serving mixes rows at different depths in one decode
+    batch); cache_len: static dense-equivalent cache length (prompt +
+    max_new).
+
+    The token's K/V are written at ``[layer, page, :, slot]`` and each
+    row's pages are gathered by ``[layer, block_table]``, so no copy
+    of the layer's slice is made: a decode tick moves the rows it
+    writes and the pages it reads, whatever the pool's size.
+
+    Bit-equivalence contract: the gathered page view sliced to
+    ``cache_len`` feeds the *same* ``decode_attention`` with the same
+    shapes and values as the dense path's contiguous cache, so the
+    output is bit-identical. Stale bytes in recycled pages sit at
+    positions > pos and are masked to the same -1e30 the dense path's
+    zero-initialised slots are, before any softmax math runs."""
+    q, k, v = _paged_decode_qkv(cfg, p, x_t, pos)
+    page_ids, slot = _write_slot(block_table, pos, k_pool.shape[3])
+    k_pool = k_pool.at[layer, page_ids, :, slot].set(
+        k.astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, page_ids, :, slot].set(
+        v.astype(v_pool.dtype))
+    k_cache = _gather_layer_pages(k_pool, layer, block_table, cache_len)
+    v_cache = _gather_layer_pages(v_pool, layer, block_table, cache_len)
+    out = decode_attention(q, k_cache, v_cache, jnp.arange(cache_len),
+                           pos)
+    return _decode_out(cfg, p, out), k_pool, v_pool
 
 
 def gqa_decode_quant_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
@@ -480,7 +532,7 @@ def gqa_decode_quant_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
                                      pos)
     out = out.reshape(b, cfg.num_heads * hd)
     # tensor parallelism: gather head-local outputs before the
-    # replicated output projection (see ``gqa_decode_paged``)
+    # replicated output projection (see ``_decode_out``)
     out = tp_all_gather(out)
     y = jnp.einsum("bh,hd->bd", out, p["wo"])
     return y, pages
@@ -543,7 +595,7 @@ def gqa_decode_ring_paged(cfg: ModelConfig, p: dict, x_t: jax.Array,
     out = decode_attention(q, k_cache, v_cache, k_positions, pos_rows)
     out = out.reshape(b, cfg.num_heads * hd)
     # tensor parallelism: gather head-local outputs before the
-    # replicated output projection (see ``gqa_decode_paged``)
+    # replicated output projection (see ``_decode_out``)
     out = tp_all_gather(out)
     y = jnp.einsum("bh,hd->bd", out, p["wo"])
     return y, pages
@@ -620,7 +672,7 @@ def gqa_prefill_chunk_paged(cfg: ModelConfig, p: dict, x: jax.Array,
     out = out.reshape(b, c, cfg.num_heads * hd)
     # tensor parallelism: gather head-local outputs to the full head
     # axis before the replicated output projection (see
-    # ``gqa_decode_paged``)
+    # ``_decode_out``)
     out = tp_all_gather(out)
     y = jnp.einsum("bsh,hd->bsd", out, p["wo"])
     return y, k_pages, v_pages

@@ -732,7 +732,9 @@ def decode_step_paged(cfg: ModelConfig, params: dict,
     recurrent state is position free).
 
     Dense/quant write each layer's K/V (codes + scales) at ``pos``
-    into the row's block-table page; ring writes at
+    into the row's block-table page (dense without Pallas carries the
+    whole pool through the layer scan and updates it in place, see
+    ``attn.gqa_decode_paged_pool``); ring writes at
     ``pos mod min(cache_len, window)``; lanes gather each row's
     recurrent state at its block-table lane id, run the SSM step, and
     scatter the new state back. Returns (logits, updated pages)."""
@@ -761,6 +763,29 @@ def decode_step_paged(cfg: ModelConfig, params: dict,
                               cfg.num_layers)
         return _logits(cfg, params, x), pages
 
+    def mlp_residual(lp, x):
+        h = norm_apply(cfg, lp["mlp_norm"], x)
+        return x + mlp_apply_token(cfg, lp["mlp"], h)
+
+    if layout == "dense" and not cfg.use_pallas:
+        # the whole pool rides in the carry and each layer writes and
+        # reads only its rows of it: no layer slice goes through the
+        # scan's xs/ys, so nothing pool-sized is copied per tick
+        def pool_body(carry, xs):
+            x, kp, vp = carry
+            lp, layer = xs
+            h = norm_apply(cfg, lp["attn_norm"], x)
+            a, kp, vp = attn.gqa_decode_paged_pool(
+                cfg, lp["attn"], h, kp, vp, layer, block_table, pos,
+                cache_len=cache_len)
+            return (mlp_residual(lp, x + a), kp, vp), None
+
+        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        (x, kp, vp), _ = stack_scan(
+            cfg, pool_body, (x, pages["k"], pages["v"]),
+            (params["layers"], layers), cfg.num_layers)
+        return _logits(cfg, params, x), {"k": kp, "v": vp}
+
     def body(x, xs):
         lp, pg = xs
         h = norm_apply(cfg, lp["attn_norm"], x)
@@ -774,13 +799,9 @@ def decode_step_paged(cfg: ModelConfig, params: dict,
                 cache_len=min(cache_len, cfg.window))
         else:
             a, kp, vp = attn.gqa_decode_paged(
-                cfg, lp["attn"], h, pg["k"], pg["v"], block_table,
-                pos, cache_len=cache_len)
+                cfg, lp["attn"], h, pg["k"], pg["v"], block_table, pos)
             pg = {"k": kp, "v": vp}
-        x = x + a
-        h = norm_apply(cfg, lp["mlp_norm"], x)
-        x = x + mlp_apply_token(cfg, lp["mlp"], h)
-        return x, pg
+        return mlp_residual(lp, x + a), pg
 
     x, pages = stack_scan(cfg, body, x, (params["layers"], pages),
                           cfg.num_layers)

@@ -459,7 +459,8 @@ def _decode_megastep_rows_impl(cfg: ModelConfig, params: dict,
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "n_ticks", "cache_len", "temperature",
-                     "eos_id", "pad_id"))
+                     "eos_id", "pad_id"),
+    donate_argnames=("pages",))
 def decode_megastep_rows(cfg: ModelConfig, params: dict,
                          logits: jax.Array, pages,
                          block_table: jax.Array,
@@ -480,7 +481,9 @@ def decode_megastep_rows(cfg: ModelConfig, params: dict,
 
     Returns (emits (K, B), dones (K, B), next_logits (B, V), pages);
     ``next_logits`` keeps each lane's pending logits on device for the
-    next megastep."""
+    next megastep. ``pages`` is donated: the returned pool is the one
+    passed in, written in place, and the caller's reference to the
+    old one is dead after the call."""
     return _decode_megastep_rows_impl(
         cfg, params, logits, pages, block_table, pos,
         row_keys, steps, done, n_ticks=n_ticks, cache_len=cache_len,
