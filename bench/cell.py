@@ -2,8 +2,10 @@
 
 Everything that belongs to a configuration, a traffic mix or a metric
 is data found by name (``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py``), so a
-later cell or metric is added as files and ``BENCHMARK.json`` entries.
+``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py``), and
+each model group's architecture by its ``model_type``
+(``bench/arch/<model_type>.py``), so a later cell, metric or
+architecture is added as files and ``BENCHMARK.json`` entries.
 """
 from __future__ import annotations
 
@@ -21,7 +23,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import capture, check, profile_reduce, traffic, weights
+from bench import arch, capture, check, profile_reduce, traffic, weights
 from bench.peaks import peaks
 
 HERE = Path(__file__).resolve().parent
@@ -56,22 +58,6 @@ def load_cell(workload: str):
     metrics = {"end_to_end": [m for m in bench["end_to_end"] if wanted(m)],
                "per_layer": [m for m in bench["per_layer"] if wanted(m)]}
     return cell, config, mix, metrics
-
-
-def model_config(group: dict, name: str, dtype: str):
-    """The program's ``ModelConfig`` for one model group of a
-    configuration file."""
-    from repro.configs.base import ModelConfig
-    d, h = group["hidden_size"], group["num_attention_heads"]
-    return ModelConfig(
-        name=name, family="dense", num_layers=group["num_hidden_layers"],
-        d_model=d, num_heads=h, num_kv_heads=group["num_key_value_heads"],
-        d_ff=group["intermediate_size"], vocab_size=group["vocab_size"],
-        head_dim=group.get("head_dim") or d // h,
-        rope_theta=float(group["rope_theta"]),
-        norm_eps=float(group["rms_norm_eps"]),
-        max_position=group["max_position_embeddings"],
-        tie_embeddings=bool(group["tie_word_embeddings"]), dtype=dtype)
 
 
 def groups(config: dict) -> list:
@@ -327,7 +313,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     models = groups(config)
     dtype = config["dtype"]
     zoo = [ZooModel(name="probe" if i == 0 else f"member{i}",
-                    cfg=model_config(g, config["name"] + (
+                    cfg=arch.of(g).model_config(g, config["name"] + (
                         "-probe" if i == 0 else "-member"), dtype),
                     params=weights.make(g, words[i], dtype))
            for i, g in enumerate(models)]

@@ -25,13 +25,12 @@ own gaps are kept beside them in ``win.checked``.
 """
 from __future__ import annotations
 
-import importlib
 import tempfile
 from pathlib import Path
 
 import numpy as np
 
-from bench import capture, weights
+from bench import arch, capture, tokenizer, weights
 from bench.reference import sampling
 
 
@@ -73,8 +72,6 @@ def run(win, words, control: bool = False):
     from repro.serving import trace_records
     from repro.teamllm.artifacts import ArtifactStore
 
-    llama = importlib.import_module(
-        f"bench.reference.{win.config['reference']}")
     max_new = win.mix["max_new_tokens"]
     n_members = len(win.models) - 1
     routes_wrong = unanswered = 0
@@ -107,7 +104,7 @@ def run(win, words, control: bool = False):
         if ci not in by_call:
             by_call[ci] = capture.lanes(c.launches, max_new)
         found = by_call[ci]
-        ids = llama.encode(c.tasks[a].text)
+        ids = tokenizer.encode(c.tasks[a].text)
         s = len(ids)
         lanes = [(0, sampling.probe_key(seed, a, j), win.temps[0], j)
                  for j in range(3)]
@@ -121,7 +118,7 @@ def run(win, words, control: bool = False):
                   and lane["positions"] == list(
                       range(s, s + len(lane["tokens"]))))
             if ok and j is not None:
-                ok = llama.decode(lane["tokens"]) == \
+                ok = tokenizer.decode(lane["tokens"]) == \
                     c.result.probe_texts[a][j]
             if not ok:
                 unmatched += 1
@@ -134,14 +131,15 @@ def run(win, words, control: bool = False):
     served = 0
     for model, items in sorted(todo.items()):
         m = win.models[model]
+        logits = arch.of(m).logits
         w = weights.make(m, words[model], win.config["dtype"])
         side = "probe" if model == 0 else "member"
         for ids, toks, key, temp in items:
             seq = np.zeros(length, np.int32)
             body = ids + toks[:-1]
             seq[:len(body)] = body
-            ref = llama.logits(m, w, seq)
-            other = llama.logits(m, w, seq, "fp8") if control else ref
+            ref = logits(m, w, seq)
+            other = logits(m, w, seq, "fp8") if control else ref
             g, gc = sampling.gaps(ref, other, len(ids) - 1, toks, key,
                                   temp, max_new)
             gaps[side].append(float(g.max()))

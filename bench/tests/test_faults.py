@@ -35,11 +35,17 @@ def test_a_token_altered_where_it_is_produced(tiny, monkeypatch):
 
 def test_a_step_that_returns_its_state_unchanged(tiny, monkeypatch):
     """Decode launches hand back the pages they were given: the keys
-    and values of the tokens they emitted are never written."""
+    and values of the tokens they emitted are never written. The
+    program donates the pool it is given, so the pages handed back are
+    a copy taken before the launch."""
+    import jax
+    import jax.numpy as jnp
+
     def wrap(fn):
         def decode(cfg, params, logits, pages, *a, **k):
+            before = jax.tree.map(jnp.copy, pages)
             emits, dones, lg, _ = fn(cfg, params, logits, pages, *a, **k)
-            return emits, dones, lg, pages
+            return emits, dones, lg, before
         return decode
     _patch(monkeypatch, "decode_megastep_rows", wrap)
     r = tiny(SEED)

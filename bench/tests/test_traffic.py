@@ -56,34 +56,34 @@ def test_single_loop_cycles_whole_blocks():
 def test_prompts_encode_to_their_class_length():
     import numpy as np
 
-    from bench.reference import llama
+    from bench import tokenizer
     rng = np.random.default_rng(0)
     for n in (12, 128, 1024):
         text = traffic.prompt_text(rng, n)
-        assert len(llama.encode(text)) == n
+        assert len(tokenizer.encode(text)) == n
 
 
 def test_program_tokenizer_agrees_with_the_reference_copy():
     import numpy as np
 
-    from bench.reference import llama
+    from bench import tokenizer
     from repro.data import tokenizer as tok
     rng = np.random.default_rng(1)
     text = traffic.prompt_text(rng, 256)
-    assert tok.encode_aligned([text])[0].tolist() == llama.encode(text)
+    assert tok.encode_aligned([text])[0].tolist() == tokenizer.encode(text)
     ids = rng.integers(0, 40, size=64)
-    assert tok.decode(ids) == llama.decode(ids)
+    assert tok.decode(ids) == tokenizer.decode(ids)
 
 
 def test_every_call_has_the_same_program_shapes():
     """Fresh text, same shapes: each call's prompts encode to the same
     lengths under the same routes, so the step programs the window
     runs are the ones set-up compiled."""
-    from bench.reference import llama
+    from bench import tokenizer
     mix = traffic.load("sweep")
     pairs = traffic.call_sequence(mix)
     prompts = traffic.Prompts(mix, 2**33 + 9)
-    shapes = [[(len(llama.encode(t.text)), t.kind) for t in
+    shapes = [[(len(tokenizer.encode(t.text)), t.kind) for t in
                prompts.tasks(pairs)] for _ in range(3)]
     assert shapes[0] == shapes[1] == shapes[2]
     assert [n for n, _ in shapes[0]] == [

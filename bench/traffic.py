@@ -26,7 +26,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench.reference.llama import CHARS
+from bench.tokenizer import CHARS
 
 TRAFFIC_DIR = Path(__file__).resolve().parent / "traffic"
 # the seed of the one order of every mix's block

@@ -2,9 +2,9 @@
 
 The benchmark, not the program, makes the weights, so that the plain
 reference can make the same ones again and take nothing from the
-program. The tree is the program's parameter layout for a dense
-Llama-style stack (``layers`` stacked along a leading axis), which is
-its input format, like a checkpoint's.
+program. The tree is the program's parameter layout for the group's
+architecture (``bench/arch``: its ``shapes``), which is its input
+format, like a checkpoint's.
 
 One jitted call per model makes every leaf, in the type it is served
 in. Matrices are normal with std ``min(0.02, 1/sqrt(fan-in))``, where
@@ -17,30 +17,7 @@ import functools
 
 import numpy as np
 
-
-def shapes(m: dict) -> dict:
-    """Leaf name -> shape for one model group of a configuration file
-    (Hugging Face key names)."""
-    d, f = m["hidden_size"], m["intermediate_size"]
-    h, kv = m["num_attention_heads"], m["num_key_value_heads"]
-    hd = m.get("head_dim") or d // h
-    n, v = m["num_hidden_layers"], m["vocab_size"]
-    out = {
-        "embedding": (v, d),
-        "final_norm/scale": (d,),
-        "layers/attn_norm/scale": (n, d),
-        "layers/attn/wq": (n, d, h * hd),
-        "layers/attn/wk": (n, d, kv * hd),
-        "layers/attn/wv": (n, d, kv * hd),
-        "layers/attn/wo": (n, h * hd, d),
-        "layers/mlp_norm/scale": (n, d),
-        "layers/mlp/w_gate": (n, d, f),
-        "layers/mlp/w_up": (n, d, f),
-        "layers/mlp/w_down": (n, f, d),
-    }
-    if not m["tie_word_embeddings"]:
-        out["lm_head"] = (d, v)
-    return out
+from bench import arch
 
 
 def _nest(flat: dict) -> dict:
@@ -76,7 +53,7 @@ def _maker(spec: tuple, dtype: str):
 def make(m: dict, seed: int, dtype: str = "bfloat16") -> dict:
     """The model's weights as a nested dict of device arrays."""
     import jax
-    spec = tuple(sorted(shapes(m).items()))
+    spec = tuple(sorted(arch.of(m).shapes(m).items()))
     flat = _maker(spec, dtype)(jax.random.PRNGKey(seed))
     return _nest(flat)
 

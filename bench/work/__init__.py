@@ -1,20 +1,17 @@
-"""Work counters, one module per architecture; a configuration file
-names its own under ``"work"``."""
+"""Work counts of a captured launch, by the architecture of the model
+group it ran (``bench/arch``: its ``decode_tick`` and
+``prefill_chunk``)."""
 from __future__ import annotations
 
-import importlib
-
-
-def counter(config: dict):
-    return importlib.import_module(f"bench.work.{config['work']}")
+from bench import arch
 
 
 def launch(win, ln) -> tuple:
     """(operations, bytes) the useful rows of one captured launch
     need."""
     from bench import capture
-    mod = counter(win.config)
     m = win.models[ln.model]
+    mod = arch.of(m)
     if ln.kind == "prefill":
         return mod.prefill_chunk(m, capture.prefill_rows(ln),
                                  ln.static["prompt_len"])
